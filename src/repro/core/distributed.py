@@ -40,25 +40,6 @@ from repro.kernels import ref
 from repro.kernels.ops import PropagateBackend
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """Version shim.  Probe kwarg acceptance, not namespace presence:
-    current jax has jax.shard_map(check_vma=), the 0.6.x window has
-    jax.shard_map(check_rep=), and older jax only ships
-    jax.experimental.shard_map.shard_map(check_rep=)."""
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    for kw in ({"check_vma": False}, {"check_rep": False}):
-        try:
-            return sm(
-                body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-            )
-        except TypeError:
-            continue
-    return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 def _pad_partition(src, dst, w, n_parts, key):
     """Split COO edges into n_parts buckets by the per-edge ``key`` array,
     padding every bucket to the max bucket size.
@@ -287,11 +268,12 @@ class ShardedBackend(PropagateBackend):
                 return self.make_local(parts)(sr, xf)
 
             fn = jax.jit(
-                _shard_map(
+                jax.shard_map(
                     body,
                     mesh=self.mesh,
                     in_specs=(P(None, None),) + self.part_specs,
                     out_specs=P(None, None),
+                    check_vma=False,
                 )
             )
             self._jitted[sr.name] = fn

@@ -306,7 +306,6 @@ class QuegelEngine(SlotProgram):
         blocks: Optional[Any] = None,
         aux_graphs: Optional[dict] = None,
         block: int = 128,
-        interpret: bool = True,
         example_query: Any = None,
         propagate_override: Optional[dict] = None,
         legacy: bool = False,
@@ -342,7 +341,6 @@ class QuegelEngine(SlotProgram):
         self.index_fn = index_fn
         self.blocks = blocks
         self.propagate_override = dict(propagate_override or {})
-        self.interpret = interpret
         self.legacy = bool(legacy)
         self.steps_per_round = int(steps_per_round)
         if self.steps_per_round < 1:
@@ -395,7 +393,6 @@ class QuegelEngine(SlotProgram):
                     f"backend instance cannot serve auxiliary views {sorted(unbound)}: "
                     "pass a spec string, or cover each view via propagate_override"
                 )
-        self._backends: dict = {}
         for name, (g_, b_) in views.items():
             if mesh is not None and g_.n != graph.n:
                 raise ValueError(
@@ -408,20 +405,27 @@ class QuegelEngine(SlotProgram):
                     "the sharded backend combines over edge partitions, not "
                     "tile tables"
                 )
-            self._backends[name] = ops.make_backend(
-                backend,
-                g_,
-                blocks=b_,
-                block=block,
-                gate=self.gate,
-                gather_edges=gather_edges,
-                interpret=interpret,
-                mesh=mesh,
-                mesh_axis=mesh_axis,
-                partition=partition,
-            )
-        for name, fn in self.propagate_override.items():
-            self._backends[name] = ops.CallableBackend(fn)
+
+        def make_backends(views) -> dict:
+            bes = {
+                name: ops.make_backend(
+                    backend,
+                    g_,
+                    blocks=b_,
+                    block=block,
+                    gate=self.gate,
+                    gather_edges=gather_edges,
+                    mesh=mesh,
+                    mesh_axis=mesh_axis,
+                    partition=partition,
+                )
+                for name, (g_, b_) in views.items()
+            }
+            for name, fn in self.propagate_override.items():
+                bes[name] = ops.CallableBackend(fn)
+            return bes
+
+        self._backends = make_backends(views)
 
         # ---- argument-carried editions (DESIGN.md §12 addendum)
         carriable = not self.legacy and all(
@@ -465,6 +469,18 @@ class QuegelEngine(SlotProgram):
         self._ac_protos: dict = {}  # plan-parameter templates for from_args
         self._spmd_ac = None        # shared SPMD entries + shardings
         self._warm_threads: list = []
+        if self._arg_carried and mesh is None and isinstance(backend, str):
+            # The carrier is every view capacity-padded.  Hold the views
+            # padded from the start so the carrier shares their arrays;
+            # padding only at bind time kept a second copy of every view's
+            # edges on the device next to the unpadded one.
+            views = {
+                name: (g_.with_capacity(max_e=self._edge_cap(name, g_)), b_)
+                for name, (g_, b_) in views.items()
+            }
+            self.graph = graph = views["default"][0]
+            self.aux_graphs = {k: v for k, v in views.items() if k != "default"}
+            self._backends = make_backends(views)
 
         if donate == "auto":
             donate = jax.default_backend() not in ("cpu",)
@@ -863,27 +879,12 @@ class QuegelEngine(SlotProgram):
         raises its view's cap (new shapes, one real recompile) and every
         later in-capacity edition reuses that compile.
         """
-        from repro.core.graph import grow_capacity
-
         graphs = {"default": ed.graph, **ed.aux}
         views: dict = {}
         g_default = None
         for name, be in ed.backends.items():
             g_v = graphs[name]
-            cap = self._view_caps.get(name)
-            if cap is None:
-                # an explicit edge_capacity= is taken at face value (tests
-                # and benches use it to provoke overflow); otherwise grow
-                # with headroom so typical delta streams never overflow
-                cap = (self._edge_capacity
-                       if self._edge_capacity is not None
-                       else grow_capacity(g_v.num_edges))
-                cap = max(cap, g_v.num_edges)
-                self._view_caps[name] = cap
-            elif g_v.num_edges > cap:
-                cap = grow_capacity(g_v.num_edges)
-                self._view_caps[name] = cap
-            gcar = g_v.with_capacity(max_e=cap).carrier()
+            gcar = g_v.with_capacity(max_e=self._edge_cap(name, g_v)).carrier()
             scap = None
             if isinstance(be, ops._TileBackend):
                 need = max(
@@ -897,6 +898,25 @@ class QuegelEngine(SlotProgram):
             if name == "default":
                 g_default = gcar
         return {"graph": g_default, "index": ed.index, "views": views}
+
+    def _edge_cap(self, name: str, g: Graph) -> int:
+        """View ``name``'s padded edge capacity for graph ``g``.  Caps only
+        grow: the first call fixes one, and a later graph that outgrows it
+        raises it (new shapes, one real recompile)."""
+        from repro.core.graph import grow_capacity
+
+        cap = self._view_caps.get(name)
+        if cap is None:
+            # an explicit edge_capacity= is taken at face value (tests and
+            # benches use it to provoke overflow); otherwise grow with
+            # headroom so typical delta streams never overflow
+            cap = (self._edge_capacity if self._edge_capacity is not None
+                   else grow_capacity(g.num_edges))
+            cap = max(cap, g.num_edges)
+        elif g.num_edges > cap:
+            cap = grow_capacity(g.num_edges)
+        self._view_caps[name] = cap
+        return cap
 
     def _ensure_arg_carried_entries(self) -> None:
         """Build the ONE shared set of jitted round entries (single-device
@@ -968,8 +988,6 @@ class QuegelEngine(SlotProgram):
         n_parts ways (DESIGN.md §6).
         """
         from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from repro.core.distributed import _shard_map
 
         g, C = ed.graph, self.capacity
         mesh, axis, nparts = self.mesh, self._mesh_axis, self._n_parts
@@ -1047,16 +1065,16 @@ class QuegelEngine(SlotProgram):
         )
         dn = (0,) if self.donate else ()
         ed.round = self._jit(
-            _shard_map(
-                body_round, mesh,
+            jax.shard_map(
+                body_round, mesh=mesh, check_vma=False,
                 in_specs=(slot_specs, P(None), edge_specs),
                 out_specs=slot_specs,
             ),
             version=ed.version, donate_argnums=dn,
         )
         ed.round_admit = self._jit(
-            _shard_map(
-                body_admit, mesh,
+            jax.shard_map(
+                body_admit, mesh=mesh, check_vma=False,
                 in_specs=(slot_specs, P(None), query_specs, P(None),
                           edge_specs),
                 out_specs=slot_specs,
@@ -1064,8 +1082,8 @@ class QuegelEngine(SlotProgram):
             version=ed.version, donate_argnums=dn,
         )
         ed.round_resume = self._jit(
-            _shard_map(
-                body_resume, mesh,
+            jax.shard_map(
+                body_resume, mesh=mesh, check_vma=False,
                 in_specs=(slot_specs, P(None), query_specs, P(None),
                           state_specs, P(None), P(None), edge_specs),
                 out_specs=slot_specs,
@@ -1113,19 +1131,7 @@ class QuegelEngine(SlotProgram):
         graph (feeds ``prog.init`` / ``StepCtx``) plus the index.  Edge
         work never reads it — that rides in the mesh-sharded partition
         arrays, passed alongside so they keep their own shardings."""
-        from repro.core.graph import grow_capacity
-
-        ne = ed.graph.num_edges
-        cap = self._view_caps.get("default")
-        if cap is None:
-            cap = (self._edge_capacity
-                   if self._edge_capacity is not None
-                   else grow_capacity(ne))
-            cap = max(cap, ne)
-            self._view_caps["default"] = cap
-        elif ne > cap:
-            cap = grow_capacity(ne)
-            self._view_caps["default"] = cap
+        cap = self._edge_cap("default", ed.graph)
         return {
             "graph": ed.graph.with_capacity(max_e=cap).carrier(),
             "index": ed.index,
@@ -1142,8 +1148,6 @@ class QuegelEngine(SlotProgram):
         if self._spmd_ac is not None:
             return
         from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from repro.core.distributed import _shard_map
 
         g, C = ed0.graph, self.capacity
         mesh, axis, nparts = self.mesh, self._mesh_axis, self._n_parts
@@ -1234,8 +1238,8 @@ class QuegelEngine(SlotProgram):
         dn = (0,) if self.donate else ()
         entries = (
             self._jit(
-                _shard_map(
-                    body_round, mesh,
+                jax.shard_map(
+                    body_round, mesh=mesh, check_vma=False,
                     in_specs=(slot_specs, P(None), edge_specs,
                               carrier_specs),
                     out_specs=slot_specs,
@@ -1243,8 +1247,8 @@ class QuegelEngine(SlotProgram):
                 donate_argnums=dn,
             ),
             self._jit(
-                _shard_map(
-                    body_admit, mesh,
+                jax.shard_map(
+                    body_admit, mesh=mesh, check_vma=False,
                     in_specs=(slot_specs, P(None), query_specs, P(None),
                               edge_specs, carrier_specs),
                     out_specs=slot_specs,
@@ -1252,8 +1256,8 @@ class QuegelEngine(SlotProgram):
                 donate_argnums=dn,
             ),
             self._jit(
-                _shard_map(
-                    body_resume, mesh,
+                jax.shard_map(
+                    body_resume, mesh=mesh, check_vma=False,
                     in_specs=(slot_specs, P(None), query_specs, P(None),
                               state_specs, P(None), P(None), edge_specs,
                               carrier_specs),

@@ -376,40 +376,35 @@ class Graph:
         src, dst, w = self._edges_np()
         dtype = dtype or w.dtype
         nb = _pad_to(self.n, block) // block
-        sb = src // block
-        db = dst // block
-        pair = db.astype(np.int64) * nb + sb
-        uniq = np.unique(pair)
-        # map (dst block) -> list of src blocks
-        rows = [[] for _ in range(nb)]
-        for p in uniq:
-            rows[int(p // nb)].append(int(p % nb))
-        max_bpr = max(1, max((len(r) for r in rows), default=1))
+        sb = src.astype(np.int64) // block
+        db = dst.astype(np.int64) // block
+        # (dst block, src block) pairs in sorted order: each dst-block row
+        # lists its source blocks ascending, one slot per source block
+        uniq, pair_of_edge = np.unique(db * nb + sb, return_inverse=True)
+        row = uniq // nb
+        nslots = np.bincount(row, minlength=nb)
+        max_bpr = max(1, int(nslots.max(initial=0)))
+        slot = np.arange(len(uniq)) - (np.cumsum(nslots) - nslots)[row]
+        # padded slots point at block 0 with identity tiles
         src_ids = np.zeros((nb, max_bpr), dtype=np.int32)
+        src_ids[row, slot] = uniq % nb
         tiles = np.full((nb, max_bpr, block, block), add_id, dtype=dtype)
-        slot_of = {}
-        for i, r in enumerate(rows):
-            for k, sblk in enumerate(r):
-                src_ids[i, k] = sblk
-                slot_of[(i, sblk)] = k
-        # padded slots point at block 0 with identity tiles (already filled)
-        for e in range(len(src)):
-            i, sblk = int(db[e]), int(sb[e])
-            k = slot_of[(i, sblk)]
-            r, c = int(src[e] % block), int(dst[e] % block)
-            if np.issubdtype(tiles.dtype, np.unsignedinteger):
-                tiles[i, k, r, c] |= w[e]
-            elif add_id == 0:
-                tiles[i, k, r, c] += w[e]
-            elif add_id > 0:  # min semirings: keep best (smallest) weight
-                tiles[i, k, r, c] = min(tiles[i, k, r, c], w[e])
-            else:  # max semirings: presence must exceed the -INF fill
-                tiles[i, k, r, c] = max(tiles[i, k, r, c], w[e])
+        cell = ((db * max_bpr + slot[pair_of_edge]) * block
+                + src % block) * block + dst % block
+        flat, wt = tiles.reshape(-1), w.astype(dtype)
+        if np.issubdtype(tiles.dtype, np.unsignedinteger):
+            np.bitwise_or.at(flat, cell, wt)
+        elif add_id == 0:
+            np.add.at(flat, cell, wt)
+        elif add_id > 0:  # min semirings: keep best (smallest) weight
+            np.minimum.at(flat, cell, wt)
+        else:  # max semirings: presence must exceed the -INF fill
+            np.maximum.at(flat, cell, wt)
         return BlockSparse(
             src_ids=jnp.asarray(src_ids),
             tiles=jnp.asarray(tiles),
             block=block,
-            nslots=jnp.asarray([len(r) for r in rows], dtype=jnp.int32),
+            nslots=jnp.asarray(nslots, dtype=jnp.int32),
         )
 
     # ---------------------------------------------------------- mutation
@@ -669,9 +664,9 @@ def barabasi_albert(n: int, m: int, seed: int = 0, directed: bool = False) -> Gr
     if not directed:
         src, dst = np.concatenate([src, dst]), np.concatenate([dst, src])
     # dedupe
-    key = src.astype(np.int64) * n + dst
-    _, idx = np.unique(key, return_index=True)
-    return Graph.from_edges(src[idx], dst[idx], n)
+    # deduplicated in (dst, src) order, the order from_edges keeps
+    key = np.unique(dst.astype(np.int64) * n + src)
+    return Graph.from_edges(key % n, key // n, n)
 
 
 def random_graph(n: int, avg_deg: float, seed: int = 0, directed: bool = True) -> Graph:
@@ -683,9 +678,9 @@ def random_graph(n: int, avg_deg: float, seed: int = 0, directed: bool = True) -
     src, dst = src[keep], dst[keep]
     if not directed:
         src, dst = np.concatenate([src, dst]), np.concatenate([dst, src])
-    key = src.astype(np.int64) * n + dst
-    _, idx = np.unique(key, return_index=True)
-    return Graph.from_edges(src[idx], dst[idx], n)
+    # deduplicated in (dst, src) order, the order from_edges keeps
+    key = np.unique(dst.astype(np.int64) * n + src)
+    return Graph.from_edges(key % n, key // n, n)
 
 
 def multi_component_graph(n_components: int, comp_size: int, avg_deg: float, seed: int = 0) -> Graph:
@@ -718,9 +713,9 @@ def random_dag(n: int, avg_deg: float, seed: int = 0) -> Graph:
     src, dst = np.minimum(a, b), np.maximum(a, b)
     keep = src != dst
     src, dst = src[keep], dst[keep]
-    key = src.astype(np.int64) * n + dst
-    _, idx = np.unique(key, return_index=True)
-    return Graph.from_edges(src[idx], dst[idx], n)
+    # deduplicated in (dst, src) order, the order from_edges keeps
+    key = np.unique(dst.astype(np.int64) * n + src)
+    return Graph.from_edges(key % n, key // n, n)
 
 
 def random_tree(n: int, max_fanout: int = 8, seed: int = 0,

@@ -27,6 +27,7 @@ Semiring flavours (static `sr_name` at trace time):
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -58,12 +59,14 @@ def _combine_tile(sr_name: str, xs, t, add_id):
     raise ValueError(sr_name)
 
 
-def _kernel(src_ids_ref, active_ref, x_ref, tiles_ref, *rest, sr_name: str, add_id):
+def _kernel(src_ids_ref, active_ref, x_ref, tiles_ref, *rest, sr_name: str,
+            add_id, max_bpr: int):
     """One (dst_block, slot) grid cell.  ``active_ref`` is the second
-    scalar-prefetch operand: a per-(i, k) activity flag (frontier-dead and
-    padding tiles are skipped — both the combine and the accumulate).  The
-    optional mask ref applies the per-lane frontier INSIDE the tile (the
-    push-down replacing the old dense pre-mask of x)."""
+    scalar-prefetch operand: a per-(i, k) activity flag, flattened to
+    ``i * max_bpr + k`` (frontier-dead and padding tiles are skipped — both
+    the combine and the accumulate).  The optional mask ref applies the
+    per-lane frontier INSIDE the tile (the push-down replacing the old
+    dense pre-mask of x)."""
     if len(rest) == 2:
         m_ref, o_ref = rest
     else:
@@ -75,7 +78,7 @@ def _kernel(src_ids_ref, active_ref, x_ref, tiles_ref, *rest, sr_name: str, add_
     def _init():
         o_ref[...] = jnp.full(o_ref.shape, add_id, o_ref.dtype)
 
-    @pl.when(active_ref[i, k] != 0)
+    @pl.when(active_ref[i * max_bpr + k] != 0)
     def _acc():
         xs = x_ref[...]
         if m_ref is not None:
@@ -89,7 +92,6 @@ def _kernel(src_ids_ref, active_ref, x_ref, tiles_ref, *rest, sr_name: str, add_
             o_ref[...] = o_ref[...] + part
 
 
-@functools.partial(jax.jit, static_argnames=("sr", "interpret"))
 def propagate_blocks(
     bs: BlockSparse,
     sr: Semiring,
@@ -97,33 +99,62 @@ def propagate_blocks(
     mask=None,
     active=None,
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Run the Pallas frontier kernel. x: (Q, V) -> (Q, V).
 
-    Q is padded to a multiple of 8, V to num_dst_blocks * B.  On this CPU
-    container ``interpret=True`` executes the kernel body for validation;
-    on a real TPU pass interpret=False.
+    Q is padded to a multiple of 8, V to num_dst_blocks * B.
 
     ``mask``   (Q, V) bool — per-lane frontier, applied per visited tile.
     ``active`` (nb, max_bpr) bool — per-tile activity flags, scalar-
                prefetched and gated with ``pl.when`` so dead tiles cost a
                flag read instead of a combine + accumulate.  None visits
                every tile (the dense baseline).
+    ``interpret`` None (the default) compiles the kernel with Mosaic on a
+               TPU and runs the Pallas interpreter on any other platform
+               (CPU tests).  ``False`` forces the compiled kernel, for
+               ahead-of-time lowering against a described TPU from a host
+               without one; ``True`` on a TPU is refused, so the device can
+               never silently run the interpreter.
     """
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        interpret = not on_tpu
+    elif interpret and on_tpu:
+        raise ValueError(
+            "interpret=True on a TPU would run the Pallas interpreter on the "
+            "device; leave interpret=None to compile the kernel"
+        )
+    if sr.name == "sum_times" and not jnp.issubdtype(x.dtype, jnp.floating):
+        raise ValueError(
+            f"sum_times on the Pallas backend needs a floating x, got "
+            f"{x.dtype}: Mosaic has no integer matmul for the tile product; "
+            "cast x and the tile table to float32, or use the coo backend"
+        )
+    return _propagate_blocks(bs, sr, x, mask, active, interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("sr", "interpret"))
+def _propagate_blocks(bs, sr, x, mask, active, *, interpret):
     q, v = x.shape
     b = bs.block
     nb, max_bpr = bs.num_dst_blocks, bs.max_bpr
     qp = max(8, ((q + 7) // 8) * 8)
     vp = nb * b
     xpad = jnp.pad(x, ((0, qp - q), (0, vp - v)), constant_values=sr.add_id)
+    # Both scalar-prefetch tables go to SMEM flat: a 2-D (nb, max_bpr)
+    # int32 table is padded to 128 words per row there, and two of them
+    # overflow the 1 MiB of SMEM from nb = 2048 (a 512x512 terrain) on.
+    ids = bs.src_ids.reshape(-1)
     if active is None:
-        act = jnp.ones((nb, max_bpr), jnp.int32)
+        act = jnp.ones((nb * max_bpr,), jnp.int32)
     else:
-        act = active.astype(jnp.int32)
+        act = active.astype(jnp.int32).reshape(-1)
 
     grid = (nb, max_bpr)
-    x_spec = pl.BlockSpec((qp, b), lambda i, k, ids, act: (0, ids[i, k]))
+    x_spec = pl.BlockSpec(
+        (qp, b), lambda i, k, ids, act: (0, ids[i * max_bpr + k])
+    )
     in_specs = [
         x_spec,
         pl.BlockSpec((1, 1, b, b), lambda i, k, ids, act: (i, k, 0, 0)),
@@ -136,7 +167,8 @@ def propagate_blocks(
         in_specs.append(x_spec)
         args.append(mpad)
     out = pl.pallas_call(
-        functools.partial(_kernel, sr_name=sr.name, add_id=sr.add_id),
+        functools.partial(_kernel, sr_name=sr.name, add_id=sr.add_id,
+                          max_bpr=max_bpr),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -145,5 +177,5 @@ def propagate_blocks(
         ),
         out_shape=jax.ShapeDtypeStruct((qp, vp), x.dtype),
         interpret=interpret,
-    )(bs.src_ids, act, *args)
+    )(ids, act, *args)
     return out[:q, :v]

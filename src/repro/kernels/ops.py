@@ -16,8 +16,8 @@ and never branches on *how* messages move.  Concrete plans:
                      frontier is given,
   * ``coo_gated``  — the same with the active-edge gather always on,
   * ``blocks_ref`` — jnp oracle over block-sparse dense tiles,
-  * ``pallas``     — the Pallas frontier kernel (TPU target; interpret-mode
-                     on CPU for validation),
+  * ``pallas``     — the Pallas frontier kernel (compiled on a TPU,
+                     interpreted on the CPU for validation),
   * ``sharded``    — edge partitions over a device mesh, one collective per
                      superstep (``core/distributed.py::ShardedBackend``).
 
@@ -43,12 +43,12 @@ from repro.kernels import frontier, ref
 
 
 def _trace_state_clean() -> bool:
-    import jax.core
+    """True outside any jax trace.  Under ``jit`` or ``eval_shape`` even a
+    constant array comes back as a tracer, so one probe array tells the two
+    apart."""
+    import jax
 
-    try:
-        return bool(jax.core.trace_state_clean())
-    except AttributeError:  # pragma: no cover - very old/new jax
-        return True
+    return not isinstance(jnp.zeros((), jnp.int32), jax.core.Tracer)
 
 
 def block_activity(bs: BlockSparse, mask) -> jnp.ndarray:
@@ -304,16 +304,13 @@ class BlocksRefBackend(_TileBackend):
 
 
 class PallasBackend(_TileBackend):
+    """The Pallas kernel: compiled with Mosaic on a TPU, interpreted on any
+    other platform (``frontier.propagate_blocks``)."""
+
     name = "pallas"
 
-    def __init__(self, graph: Graph, *, interpret: bool = True, **kw):
-        super().__init__(graph, **kw)
-        self.interpret = bool(interpret)
-
     def _run(self, bs, sr, flat, mflat, active):
-        return frontier.propagate_blocks(
-            bs, sr, flat, mask=mflat, active=active, interpret=self.interpret
-        )
+        return frontier.propagate_blocks(bs, sr, flat, mask=mflat, active=active)
 
 
 class CallableBackend(PropagateBackend):
@@ -337,7 +334,6 @@ def make_backend(
     block: int = 128,
     gate: bool = True,
     gather_edges: Optional[int] = None,
-    interpret: bool = True,
     strict_tables: bool = False,
     mesh=None,
     mesh_axis: Optional[str] = None,
@@ -368,7 +364,7 @@ def make_backend(
             )
         kw = dict(tables=blocks, block=block, gate=gate, strict=strict_tables)
         if spec == "pallas":
-            return PallasBackend(graph, interpret=interpret, **kw)
+            return PallasBackend(graph, **kw)
         return BlocksRefBackend(graph, **kw)
     if spec == "sharded":
         from repro.core.distributed import ShardedBackend, ShardedGraph
@@ -395,7 +391,6 @@ def propagate(
     *,
     blocks: Optional[Union[BlockSparse, dict]] = None,
     backend: Union[str, PropagateBackend] = "coo",
-    interpret: bool = True,
     gate: bool = True,
     gather_edges: Optional[int] = None,
     mesh=None,
@@ -420,7 +415,6 @@ def propagate(
         backend,
         graph,
         blocks=blocks,
-        interpret=interpret,
         gate=gate,
         gather_edges=gather_edges,
         strict_tables=True,

@@ -267,6 +267,7 @@ def lower_cell(arch: str, shape: str, multi_pod: bool = False, compile_: bool = 
                 coll_bytes=cost["coll"], coll_detail=cost["coll_detail"],
                 model_flops=RL.model_flops_per_device(cfg, sc, n_devices),
                 peak_mem_bytes=float(getattr(ma, "temp_size_in_bytes", 0) or 0),
+                device_kind=RL.V5E,
             )
             result.update(
                 status="compiled",

@@ -133,8 +133,9 @@ def main():
     print(f"memory_analysis: {ma}")
     print(f"cost: flops/dev={res['flops']:.3e} bytes/dev={res['bytes']:.3e} "
           f"coll/dev={res['coll_bytes']:.3e}")
-    t_coll = res["coll_bytes"] / RL.ICI_BW
-    t_mem = res["bytes"] / RL.HBM_BW
+    pk = RL.peaks(RL.V5E)
+    t_coll = res["coll_bytes"] / pk["ici_bw"]
+    t_mem = res["bytes"] / pk["hbm_bw"]
     print(f"roofline: memory={t_mem*1e3:.1f}ms collective={t_coll*1e3:.1f}ms "
           f"per super-round (C={C} queries share ONE barrier)")
     os.makedirs(args.out, exist_ok=True)

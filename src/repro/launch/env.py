@@ -1,11 +1,12 @@
-"""Runtime environment tuning for CPU serving (SNIPPETS.md 1/2 pattern).
+"""Runtime environment tuning for serving hosts (SNIPPETS.md 1/2 pattern).
 
 The HomebrewNLP/olmax launch scripts bake two classes of host tuning into
 ``run.sh`` before the Python process starts: (1) ``LD_PRELOAD`` tcmalloc —
 XLA's host allocator pressure under many small per-round transfers is
 exactly the workload glibc malloc fragments on — and (2) XLA/JAX process
 flags (``--xla_force_host_platform_device_count`` for SPMD-on-CPU,
-quieting TF logging, pinning the platform).  Neither can be applied from
+quieting TF logging).  The platform is never pinned here: JAX picks the
+accelerator when there is one.  Neither can be applied from
 inside an already-initialized process: ``LD_PRELOAD`` is consumed by the
 dynamic linker at exec time, and ``XLA_FLAGS`` is read when the backend
 initializes.  So this module is detect-and-advise:
@@ -16,7 +17,9 @@ initializes.  So this module is detect-and-advise:
 * ``apply()``   — best-effort: set the env vars that are still unset in
   an environment dict BEFORE jax is imported (no-op for LD_PRELOAD),
 * ``describe()`` — the one-line summary benchmarks print so every
-  committed number says which tunings it ran under.
+  committed number says which tunings it ran under,
+* ``enable_compile_cache()`` — JAX's persistent compilation cache, called
+  by the entry points (``chip_smoke.py``, ``benchmarks/run.py``).
 
 CLI::
 
@@ -41,6 +44,13 @@ TCMALLOC_GLOBS = (
 # Matches SNIPPETS.md 1: silence the TF/XLA C++ log spew that otherwise
 # dominates serving stdout.
 TF_LOG_LEVEL = "4"
+
+# The compile cache's home when JAX_COMPILATION_CACHE_DIR is unset: a fixed
+# directory of the checkout (git-ignored).  JAX keys entries on the path,
+# so it must not move between runs.
+COMPILE_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..", ".jax_cache"
+))
 
 
 def find_tcmalloc() -> Optional[str]:
@@ -100,13 +110,6 @@ def advise(host_devices: Optional[int] = None,
                   "has a mesh on CPU",
     })
     rows.append({
-        "var": "JAX_PLATFORMS",
-        "value": "cpu",
-        "active": d["jax_platforms"] == "cpu",
-        "reason": "skip accelerator plugin probing at import on "
-                  "CPU-only serving hosts",
-    })
-    rows.append({
         "var": "TF_CPP_MIN_LOG_LEVEL",
         "value": TF_LOG_LEVEL,
         "active": d["tf_log_level"] == TF_LOG_LEVEL,
@@ -133,6 +136,28 @@ def apply(env: Optional[dict] = None, *, host_devices: Optional[int] = None,
         env[var] = row["value"]
         applied[var] = row["value"]
     return applied
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Call from an entry point before the first compile, never at import.
+    When ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and no
+    other directory is set here; otherwise the cache goes to
+    ``COMPILE_CACHE_DIR``.  Every program is cached, however small or quick
+    to compile: a round's entry points compile in well under JAX's default
+    one-second floor, and a fresh machine would otherwise recompile them on
+    every run.
+    """
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path
 
 
 def shell_exports(host_devices: Optional[int] = None) -> str:

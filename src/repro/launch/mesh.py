@@ -18,17 +18,10 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_mesh(shape: tuple, axes: tuple):
-    """Arbitrary mesh (tests, elastic scaling).
-
-    ``axis_types`` only exists on newer jax (and ``jax.sharding.AxisType``
-    raises AttributeError, not just TypeError, where absent) — fall back
-    to the plain constructor on either."""
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    except (TypeError, AttributeError):
-        return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (tests, elastic scaling), every axis ``Auto``."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def host_device_mesh(n: Optional[int] = None, axis: str = "w"):

@@ -1,10 +1,11 @@
 """Roofline accounting from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), in seconds (TPU v5e constants):
+Three terms per (arch x shape x mesh), in seconds, from the peaks of the
+chip the cell targets (``PEAKS``, keyed by ``device_kind``):
 
-  compute    = HLO_FLOPs_per_device / 197e12        (bf16 peak per chip)
-  memory     = HLO_bytes_per_device / 819e9         (HBM bandwidth)
-  collective = collective_bytes_per_device / 50e9   (per-link ICI)
+  compute    = HLO_FLOPs_per_device / peak FLOP/s   (bf16 per chip)
+  memory     = HLO_bytes_per_device / HBM bytes/s
+  collective = collective_bytes_per_device / ICI bytes/s
 
 ``cost_analysis`` of the SPMD-partitioned executable reports the
 *per-device* program, so flops/bytes need no further division.
@@ -20,9 +21,28 @@ import json
 import re
 from typing import Optional
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes/s
-ICI_BW = 50e9  # bytes/s/link
+# ``jax.devices()[0].device_kind`` of a TPU v5e chip.
+V5E = "TPU v5 lite"
+
+# Published per-chip peaks by device kind.  TPU v5e: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of inter-chip interconnect.
+PEAKS = {
+    V5E: dict(flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8),
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """``{flops, hbm_bw, ici_bw}`` (per second) for one chip; a device kind
+    with no published peaks here is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}: add them "
+            "to roofline.PEAKS with their source"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -76,18 +96,22 @@ class Roofline:
     coll_detail: dict
     model_flops: float  # useful flops per device (6ND / 2ND)
     peak_mem_bytes: float  # from memory_analysis
+    device_kind: str  # the chip whose PEAKS bound the terms
+
+    def __post_init__(self):
+        self.peaks = peaks(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks["flops"]
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_accessed / HBM_BW
+        return self.bytes_accessed / self.peaks["hbm_bw"]
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / self.peaks["ici_bw"]
 
     @property
     def bottleneck(self) -> str:
@@ -108,7 +132,7 @@ class Roofline:
         """Fraction of peak the *useful* model flops achieve at the bound."""
         if self.bound_time == 0:
             return 0.0
-        return (self.model_flops / PEAK_FLOPS) / self.bound_time
+        return (self.model_flops / self.peaks["flops"]) / self.bound_time
 
     def to_dict(self) -> dict:
         return dict(
@@ -116,6 +140,7 @@ class Roofline:
             flops=self.flops, bytes_accessed=self.bytes_accessed,
             coll_bytes=self.coll_bytes, coll_detail=self.coll_detail,
             model_flops=self.model_flops, peak_mem_bytes=self.peak_mem_bytes,
+            device_kind=self.device_kind,
             t_compute=self.t_compute, t_memory=self.t_memory,
             t_collective=self.t_collective, bottleneck=self.bottleneck,
             useful_ratio=self.useful_ratio,
@@ -139,7 +164,7 @@ def model_flops_per_device(cfg, shape_cfg, n_devices: int) -> float:
 
 
 def build(arch: str, shape: str, mesh_name: str, cfg, shape_cfg, compiled,
-          hlo_text: str, n_devices: int) -> Roofline:
+          hlo_text: str, n_devices: int, device_kind: str) -> Roofline:
     ca = compiled.cost_analysis()
     if isinstance(ca, list):
         ca = ca[0]
@@ -156,5 +181,5 @@ def build(arch: str, shape: str, mesh_name: str, cfg, shape_cfg, compiled,
         flops=flops, bytes_accessed=byt,
         coll_bytes=float(coll["total"]), coll_detail=coll,
         model_flops=model_flops_per_device(cfg, shape_cfg, n_devices),
-        peak_mem_bytes=peak,
+        peak_mem_bytes=peak, device_kind=device_kind,
     )

@@ -1,6 +1,7 @@
 """Frontier-propagation kernel validation: Pallas (interpret) and the
 block-sparse jnp oracle must match the COO segment-reduction reference,
 swept over shapes, dtypes and semirings."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -196,3 +197,107 @@ def test_pallas_float_min_plus():
     want = np.asarray(ref.propagate_coo(g, MIN_PLUS, jnp.asarray(x)))
     got = np.asarray(frontier.propagate_blocks(bs, MIN_PLUS, jnp.asarray(x), interpret=True))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["constant", "traced"])
+@pytest.mark.parametrize("sr", SEMIRINGS[:4], ids=lambda s: s.name)
+def test_coo_scan_combine_matches_scatter(sr, traced):
+    """The scatter-free coo combine equals a segment scatter over the same
+    edges: a hub of in-degree 257 whose best message is its first (nine
+    scan steps, not eight), vertices with no in-edges, multi-edges and
+    capacity padding; with the graph held as a trace constant and as a
+    traced argument (the argument-carried round)."""
+    rng = np.random.default_rng(11)
+    n, hub = 300, 299
+    src = np.concatenate([rng.integers(0, n, 900), np.arange(257)])
+    dst = np.concatenate([rng.integers(0, n // 2, 900), np.full(257, hub)])
+    w = np.concatenate([rng.integers(1, 9, 900), np.ones(257)]).astype(np.int32)
+    g = Graph.from_edges(src, dst, n, w=w).with_capacity(max_e=len(src) + 77)
+    x = _rand_x(rng, sr, n, 3)
+    f = rng.random((3, n)) < 0.7
+    x[:, 0], f[:, 0] = (-50 if sr.add_id > 0 else 50), True
+    prop = lambda g, x, f: ref.propagate_coo(g, sr, x, f)
+    got = np.asarray((jax.jit(prop) if traced else prop)(g, jnp.asarray(x), jnp.asarray(f)))
+    add_id = np.int32(sr.add_id)
+    msgs = np.asarray(ref.apply_mul(sr, jnp.asarray(np.where(f, x, add_id)[:, src]),
+                                    jnp.asarray(w)))
+    want = np.asarray(jax.vmap(
+        lambda m: sr.segment_combine(m, jnp.asarray(dst, jnp.int32), n))(jnp.asarray(msgs)))
+    want = np.minimum(want, add_id) if add_id > 0 else np.maximum(want, add_id)
+    np.testing.assert_array_equal(got, want)
+
+
+def _to_blocks_loop(g: Graph, block: int, add_id, dtype):
+    """Per-edge loop reference for ``Graph.to_blocks``."""
+    src, dst, w = (np.asarray(a) for a in (g.src, g.dst, g.w))
+    nb = -(-g.n // block)
+    rows = [[] for _ in range(nb)]
+    for p in np.unique((dst // block).astype(np.int64) * nb + src // block):
+        rows[int(p // nb)].append(int(p % nb))
+    max_bpr = max(1, max(len(r) for r in rows))
+    src_ids = np.zeros((nb, max_bpr), np.int32)
+    tiles = np.full((nb, max_bpr, block, block), add_id, dtype=dtype)
+    for i, r in enumerate(rows):
+        src_ids[i, : len(r)] = r
+    for s, d, ww in zip(src, dst, w):
+        i, k = d // block, rows[d // block].index(s // block)
+        cell = (i, k, s % block, d % block)
+        if add_id == 0:
+            tiles[cell] += ww
+        elif add_id > 0:
+            tiles[cell] = min(tiles[cell], ww)
+        else:
+            tiles[cell] = max(tiles[cell], ww)
+    return src_ids, tiles, np.array([len(r) for r in rows], np.int32)
+
+
+@pytest.mark.parametrize("sr", SEMIRINGS, ids=lambda s: s.name)
+@pytest.mark.parametrize("n,block", [(1, 8), (70, 16), (300, 128)])
+def test_to_blocks_matches_loop_reference(sr, n, block):
+    """The vectorised tile build equals the per-edge loop, duplicates and
+    empty rows included."""
+    rng = np.random.default_rng(n + block)
+    e = 4 * n
+    src, dst = rng.integers(0, n, e), rng.integers(0, n, e)
+    w = rng.integers(1, 9, e).astype(np.float32 if sr.name == "sum_times" else np.int32)
+    g = Graph.from_edges(src, dst, n, w=w, weight_dtype=w.dtype)
+    bs = g.to_blocks(block, sr.add_id)
+    src_ids, tiles, nslots = _to_blocks_loop(g, block, sr.add_id, w.dtype)
+    np.testing.assert_array_equal(np.asarray(bs.src_ids), src_ids)
+    np.testing.assert_array_equal(np.asarray(bs.tiles), tiles)
+    np.testing.assert_array_equal(np.asarray(bs.nslots), nslots)
+
+
+def test_trace_state_clean_inside_jit():
+    """The guard that keeps in-trace tile tables out of the cache must see
+    a ``jax.jit`` trace as unclean."""
+    seen = []
+    jax.jit(lambda x: (seen.append(ops._trace_state_clean()), x)[1])(1)
+    jax.eval_shape(lambda x: (seen.append(ops._trace_state_clean()), x)[1], 1)
+    assert seen == [False, False]
+    assert ops._trace_state_clean() is True
+
+
+def test_tile_table_built_in_a_trace_is_not_cached(small_directed):
+    """A table a jit trace builds holds that trace's values: it must not
+    outlive the trace in the backend's cache, while one built eagerly is
+    kept."""
+    g = small_directed
+    be = ops.make_backend("blocks_ref", g, block=16)
+    x = jnp.asarray(_rand_x(np.random.default_rng(1), MIN_RIGHT, g.n, 2))
+    traced = np.asarray(jax.jit(lambda x: be.propagate(MIN_RIGHT, x))(x))
+    assert be.tables == {}
+    eager = np.asarray(be.propagate(MIN_RIGHT, x))
+    assert set(be.tables) == {"min_right"}
+    np.testing.assert_array_equal(traced, eager)
+
+
+def test_interpret_is_refused_on_a_tpu(monkeypatch):
+    """On a TPU the kernel is always compiled: asking for the interpreter
+    there is an error, not a silent slowdown."""
+    g = random_graph(40, 2.0, seed=1)
+    bs = g.to_blocks(8, MIN_RIGHT.add_id)
+    x = jnp.zeros((1, g.n), jnp.int32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        frontier.propagate_blocks(bs, MIN_RIGHT, x, interpret=True)

@@ -668,6 +668,29 @@ def test_arg_carried_zero_recompiles(tail_graph):
     assert grown, "overflow must recompile"
 
 
+def test_arg_carried_carrier_shares_the_view_arrays(tail_graph):
+    """An arg-carried engine holds each view capacity-padded from the
+    start, so the round's carrier is those arrays, not a second copy of
+    the graph on the device; still so after an in-capacity delta."""
+    g = tail_graph
+    eng = make_bibfs_engine(g, capacity=2, arg_carried=True)
+
+    def check_shared():
+        ed = eng._editions[eng._current_version]
+        (carrier,) = ed.round_args
+        views = {"default": ed.graph, **ed.aux}
+        for name, args in carrier["views"].items():
+            for leaf in ("src", "dst", "w", "csr_src", "csr_dst", "csr_w"):
+                assert getattr(args["graph"], leaf) is getattr(views[name], leaf)
+        assert carrier["graph"].src is ed.graph.src
+
+    check_shared()
+    assert eng.graph.content_hash() == g.content_hash()
+    assert eng.graph.num_edges == g.num_edges < eng.graph.edge_capacity
+    eng.apply_delta(adds=[(0, 1), (2, 3)], dels=None)
+    check_shared()
+
+
 def test_arg_carried_mode_resolution(tail_graph):
     g = tail_graph
     # auto: resolved by the edge-count threshold

@@ -37,14 +37,27 @@ def test_collective_bytes_parse():
 def test_roofline_terms():
     r = RL.Roofline(
         arch="a", shape="s", mesh="m",
-        flops=197e12, bytes_accessed=819e9, coll_bytes=50e9,
+        flops=197e12, bytes_accessed=819e9, coll_bytes=200e9,
         coll_detail={}, model_flops=98.5e12, peak_mem_bytes=0,
+        device_kind="TPU v5 lite",
     )
     assert abs(r.t_compute - 1.0) < 1e-9
     assert abs(r.t_memory - 1.0) < 1e-9
-    assert abs(r.t_collective - 1.0) < 1e-9
+    assert abs(r.t_collective - 1.0) < 1e-9  # 1,600 Gbit/s = 200 GB/s
     assert r.useful_ratio == 0.5
     assert abs(r.roofline_fraction - 0.5) < 1e-9
+    assert r.to_dict()["device_kind"] == "TPU v5 lite"
+
+
+def test_roofline_unknown_device_is_an_error():
+    with pytest.raises(ValueError, match="no published peaks"):
+        RL.peaks("TPU v99")
+    with pytest.raises(ValueError, match="no published peaks"):
+        RL.Roofline(
+            arch="a", shape="s", mesh="m", flops=1.0, bytes_accessed=1.0,
+            coll_bytes=1.0, coll_detail={}, model_flops=1.0,
+            peak_mem_bytes=0, device_kind="cpu",
+        )
 
 
 def test_model_flops_train_vs_decode():
@@ -97,8 +110,7 @@ def test_dryrun_machinery_reduced_mesh():
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src")]
         + env.get("PYTHONPATH", "").split(os.pathsep))
-    # pin the platform: without it jax probes for TPU/GPU plugins, which
-    # can stall for minutes in this container (see test_distributed.py)
+    # the forced host devices exist only on the cpu platform
     env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", DRYRUN_SCRIPT], capture_output=True,
                        text=True, env=env, timeout=540)
