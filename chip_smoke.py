@@ -89,9 +89,9 @@ def say(phase: str, **kv) -> None:
     print(f"{phase}: " + " ".join(f"{k}={v}" for k, v in kv.items()), flush=True)
 
 
-def peak_bytes(dev):
+def peak_bytes(dev, key: str = "peak_bytes_in_use"):
     stats = dev.memory_stats()
-    return None if stats is None else stats.get("peak_bytes_in_use")
+    return None if stats is None else stats.get(key)
 
 
 # ------------------------------------------------------------ references
@@ -333,6 +333,8 @@ def phase_mesh(args, rng) -> None:
     eng = make_bibfs_engine(g, capacity=CAPACITY, mesh=host_device_mesh(),
                             partition="dst")
     t_build = time.perf_counter() - t0
+    gc.collect()
+    built = [peak_bytes(d, "bytes_in_use") for d in devs]
     t0 = time.perf_counter()
     got_mesh = drain(eng, pairs)
     t_mesh = time.perf_counter() - t0
@@ -359,6 +361,7 @@ def phase_mesh(args, rng) -> None:
     say("mesh", devices=len(devs), partition="dst", vertices=g.n,
         edges=g.num_edges, queries=len(pairs), rounds=rounds_mesh,
         mesh_equals_single_device=True, all_match_scipy=True,
+        bytes_in_use_after_build_per_device=built,
         peak_bytes_in_use_per_device=peaks, gen_s=t_gen,
         build_s=t_build, mesh_drain_s=t_mesh,
         single_device_s=t_one)

@@ -187,8 +187,7 @@ def _relabel_hubs(graph: Graph, is_hub, hub_ids, rows):
     in-neighbor (every frontier sender carries a finite distance), and its
     pre flag is set iff some such sender is another hub or flagged itself.
     Returns ``(dist, pre)`` as ``(m, V)`` numpy arrays."""
-    src = np.asarray(graph.src)
-    dst = np.asarray(graph.dst)
+    src, dst, _ = graph._edges_np()
     n = graph.n
     is_hub_np = np.asarray(is_hub)
     hubs = np.asarray(hub_ids, np.int32)[np.asarray(rows)]

@@ -66,7 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.graph import EdgeDelta, Graph
+from repro.core.graph import BlockSparse, EdgeDelta, Graph
 from repro.core.runtime import (
     DONE, QueryTimeoutError, ResumeAdmission, RoundOutcome, SlotProgram,
     SlotRuntime, SlotStats, default_cache_key)
@@ -406,33 +406,17 @@ class QuegelEngine(SlotProgram):
                     "tile tables"
                 )
 
-        def make_backends(views) -> dict:
-            bes = {
-                name: ops.make_backend(
-                    backend,
-                    g_,
-                    blocks=b_,
-                    block=block,
-                    gate=self.gate,
-                    gather_edges=gather_edges,
-                    mesh=mesh,
-                    mesh_axis=mesh_axis,
-                    partition=partition,
-                )
-                for name, (g_, b_) in views.items()
-            }
-            for name, fn in self.propagate_override.items():
-                bes[name] = ops.CallableBackend(fn)
-            return bes
-
-        self._backends = make_backends(views)
-
         # ---- argument-carried editions (DESIGN.md §12 addendum)
-        carriable = not self.legacy and all(
-            not isinstance(be, ops.CallableBackend)
-            and getattr(be, "_shared", None) is None
-            for be in self._backends.values()
-        )
+        # Carriable: no override callables, and no tile view sharing one
+        # single-semiring table (its add-identity is unknown).
+        if isinstance(backend, ops.PropagateBackend):
+            shared = getattr(backend, "_shared", None) is not None
+        else:
+            shared = backend in ("blocks_ref", "pallas") and any(
+                isinstance(b_, BlockSparse) for _, b_ in views.values())
+        carriable = (not self.legacy and not self.propagate_override
+                     and not isinstance(backend, ops.CallableBackend)
+                     and not shared)
         if arg_carried == "auto":
             self._arg_carried = (
                 carriable and graph.num_edges >= int(arg_carried_threshold)
@@ -480,7 +464,22 @@ class QuegelEngine(SlotProgram):
             }
             self.graph = graph = views["default"][0]
             self.aux_graphs = {k: v for k, v in views.items() if k != "default"}
-            self._backends = make_backends(views)
+        self._backends = {
+            name: ops.make_backend(
+                backend,
+                g_,
+                blocks=b_,
+                block=block,
+                gate=self.gate,
+                gather_edges=gather_edges,
+                mesh=mesh,
+                mesh_axis=mesh_axis,
+                partition=partition,
+            )
+            for name, (g_, b_) in views.items()
+        }
+        for name, fn in self.propagate_override.items():
+            self._backends[name] = ops.CallableBackend(fn)
 
         if donate == "auto":
             donate = jax.default_backend() not in ("cpu",)
@@ -1102,9 +1101,7 @@ class QuegelEngine(SlotProgram):
                 self._slots, to_shardings(slot_specs)
             )
             self._slots_placed = True
-        edge_parts = jax.device_put(edge_parts, to_shardings(edge_specs))
-        ed.round_args = (edge_parts,)
-        self._edge_parts = edge_parts  # current edition's, for introspection
+        ed.round_args = (self._place_edge_parts(ed, to_shardings(edge_specs)),)
 
         # Collective payload model from the discovery pass (_build): one
         # entry per propagate call per superstep, each a (C, ..., V) slab.
@@ -1303,13 +1300,23 @@ class QuegelEngine(SlotProgram):
             self._slots_placed = True
         # pre-place this edition's arrays in the round's layout so no
         # per-call resharding (and so jit's sharding cache key is stable)
-        edge_parts = {k: be.parts for k, be in ed.backends.items()}
-        edge_parts = jax.device_put(edge_parts, ac["edge_shardings"])
+        edge_parts = self._place_edge_parts(ed, ac["edge_shardings"])
         carrier = jax.device_put(
             self._make_spmd_carrier(ed), ac["carrier_shardings"]
         )
         ed.round_args = (edge_parts, carrier)
-        self._edge_parts = edge_parts
+
+    def _place_edge_parts(self, ed: _Edition, shardings) -> dict:
+        """Put ``ed``'s edge partitions on the mesh and hand the placed
+        arrays back to its backends: the unsharded copy built on the
+        default device is then freed, not kept next to its shards."""
+        parts = jax.device_put(
+            {k: be.parts for k, be in ed.backends.items()}, shardings
+        )
+        for k, be in ed.backends.items():
+            be.sg.srcp, be.sg.dstp, be.sg.wp, be.sg.valid = parts[k]
+        self._edge_parts = parts  # current edition's, for introspection
+        return parts
 
     def collective_bytes_per_round(self) -> Optional[dict]:
         """Modeled per-device wire bytes for one SPMD super-round
@@ -1708,7 +1715,8 @@ class QuegelEngine(SlotProgram):
         # ---- index maintenance (incremental or rebuild — fn decides)
         new_index, index_info = None, None
         if cur.index is not None:
-            new_index, index_info = fn(new_graph, cur.index, delta)
+            # maintainers see the logical graph, never capacity padding
+            new_index, index_info = fn(new_graph.trimmed(), cur.index, delta)
 
         new_hash = new_graph.content_hash()
         if rt.journal is not None and not _from_journal:

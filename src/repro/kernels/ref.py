@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.graph import BlockSparse, Graph
 from repro.core.semiring import INF, Semiring
@@ -49,52 +48,15 @@ def propagate_coo(graph: Graph, sr: Semiring, x: jnp.ndarray, frontier=None) -> 
     add_id = jnp.asarray(sr.add_id, x.dtype)
     if frontier is not None:
         x = jnp.where(frontier, x, add_id)
+
+    def one(xv):
+        msgs = apply_mul(sr, xv[graph.src], graph.w)
+        out = sr.segment_combine(msgs, graph.dst, graph.n)
+        return _clamp_empty(sr, out, add_id)
+
     flat = x.reshape((-1, x.shape[-1]))
-    msgs = apply_mul(sr, flat[:, graph.src], graph.w)  # (L, E)
-    if sr.name == "sum_times":
-        # a float sum depends on its order: keep the scatter's
-        out = jax.vmap(lambda m: sr.segment_combine(m, graph.dst, graph.n))(msgs)
-    else:
-        out = _segment_scan_combine(sr, msgs, graph)
-    return _clamp_empty(sr, out, add_id).reshape(x.shape)
-
-
-def _segment_scan_combine(sr: Semiring, msgs, graph: Graph):
-    """Min or max of (L, E) messages per destination, with no scatter.
-
-    The edges are dst-sorted, so each destination's messages are one run.
-    A segmented Hillis-Steele scan folds in the message ``2**j`` edges back
-    at step j while it has the same destination; after
-    ``ceil(log2(max in-degree))`` steps the last edge of each run holds the
-    whole run's combine, read out with one gather of V indices.  On a TPU
-    v5e, scattering 8 lanes of 84M messages took 7.9 s, the gather that
-    made them 0.8 s (PERF.md).  A graph the trace holds as a constant
-    fixes the number of steps; a traced one (the argument-carried round)
-    takes one step per bit of E, enough for any graph of the same shapes.
-    Each step starts behind an optimization barrier, so that XLA cannot
-    hoist every step's run mask ahead of the scan: the steps then need two
-    (L, E) buffers in turn and nothing more (compiled for a v5e, two 8-lane
-    propagates over 84M edges take 5.4 GB of temporaries, not 8.8).
-    Capacity-padding
-    edges (dst = n) sit after every real run and are never read out.
-    """
-    add_id = jnp.asarray(sr.add_id, msgs.dtype)
-    dst, deg = graph.dst, graph.in_deg
-    e = dst.shape[0]
-    if e == 0:
-        return jnp.full(msgs.shape[:-1] + (graph.n,), add_id)
-    if isinstance(deg, jax.core.Tracer):
-        steps = (e - 1).bit_length()
-    else:
-        steps = max(int(np.asarray(deg).max()) - 1, 0).bit_length()
-    for j in range(steps):
-        s = 1 << j
-        msgs, d = jax.lax.optimization_barrier((msgs, dst))
-        back = jnp.pad(msgs[:, :-s], ((0, 0), (s, 0)), constant_values=add_id)
-        same = jnp.pad(d[:-s], (s, 0), constant_values=-1) == d
-        msgs = jnp.where(same, sr.add(msgs, back), msgs)
-    last = jnp.maximum(jnp.cumsum(deg) - 1, 0)
-    return jnp.where(deg > 0, msgs[:, last], add_id)
+    out = jax.vmap(one)(flat)
+    return out.reshape(x.shape)
 
 
 def _clamp_empty(sr: Semiring, out, add_id):

@@ -265,11 +265,14 @@ def test_hub2_incremental_matches_pinned_rebuild(small_undirected):
     assert same is inc and info_e["affected_hubs"] == 0
 
 
-def test_hub2_engine_maintains_index_through_apply_delta(small_undirected):
+@pytest.mark.parametrize("arg_carried", [False, True],
+                         ids=["constant", "arg_carried"])
+def test_hub2_engine_maintains_index_through_apply_delta(small_undirected,
+                                                         arg_carried):
     g = small_undirected
     idx = hub2.build_hub_index(g, 8)
     eng = hub2.make_hub2_engine(
-        g, idx, capacity=2,
+        g, idx, capacity=2, arg_carried=arg_carried,
         index_fn=hub2.hub_index_updater(threshold=0.5))
     q = jnp.asarray([1, 50], jnp.int32)
     qid0 = eng.submit(q)
@@ -279,12 +282,13 @@ def test_hub2_engine_maintains_index_through_apply_delta(small_undirected):
     a, b = _non_edge(g, rng)
     info = eng.apply_delta(adds=[(a, b), (b, a)])
     assert info["index"]["mode"] == "incremental"
+    assert info["index"]["affected_hubs"] > 0  # the relabel path ran
     qid1 = eng.submit(q)
     r1 = eng.run_until_drained()[qid1]
 
     # truth: fresh engine on the mutated graph with the OLD hubs pinned
     # (incremental maintenance never re-picks the hub set)
-    g1 = eng.graph
+    g1 = eng.graph.trimmed()
     idx1 = hub2.build_hub_index(g1, 8, hubs=np.asarray(idx.hub_ids))
     fresh = hub2.make_hub2_engine(g1, idx1, capacity=2)
     fid = fresh.submit(q)

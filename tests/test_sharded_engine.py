@@ -255,6 +255,35 @@ def test_one_part_mesh_parity(small_directed):
     assert eng.collective_bytes_per_round()["n_parts"] == 1
 
 
+@pytest.mark.parametrize("arg_carried", [False, True],
+                         ids=["constant", "arg_carried"])
+def test_mesh_backends_hold_only_the_placed_partitions(small_directed,
+                                                       arg_carried):
+    """The backends keep the mesh-placed partition arrays, not the unsharded
+    copy they were built as, which would stay on the default device next
+    to its shards; still so after a delta."""
+    from jax.sharding import NamedSharding
+
+    from repro.apps.ppsp import make_bibfs_engine
+
+    eng = make_bibfs_engine(small_directed, capacity=2,
+                            mesh=make_mesh((1,), ("w",)),
+                            arg_carried=arg_carried)
+
+    def check_placed():
+        ed = eng._editions[eng._current_version]
+        for name, be in ed.backends.items():
+            placed = ed.round_args[0][name]
+            assert all(a is b for a, b in zip(be.parts, placed))
+            assert all(isinstance(a.sharding, NamedSharding) for a in placed)
+
+    check_placed()
+    eng.apply_delta(adds=[(0, 1)], dels=None)
+    eng.submit(jnp.asarray([0, 5], jnp.int32))
+    eng.run_until_drained()
+    check_placed()
+
+
 def test_graph_padded():
     g = random_graph(60, 3.0, seed=2, directed=True)
     assert g.padded(4) is g  # 60 % 4 == 0 already
