@@ -98,7 +98,7 @@ class HubLabelBFS(VertexProgram):
         return dict(dist=state["dist"], pre=state["pre"])
 
     def frontier_of(self, state):
-        return state["frontier"]
+        return {"default": state["frontier"]}
 
 
 def build_hub_index(graph: Graph, k: int, capacity: int = 8, backend: str = "coo",
@@ -366,7 +366,7 @@ class Hub2PPSP(VertexProgram):
         )
 
     def frontier_of(self, state):
-        return dict(ff=state["ff"], fb=state["fb"])
+        return {"default": state["ff"], "rev": state["fb"]}
 
 
 def make_hub2_engine(graph: Graph, index: HubIndex, capacity: int = 8, **kw):

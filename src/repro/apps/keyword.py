@@ -80,7 +80,7 @@ class GraphKeywordSearch(VertexProgram):
         return dict(enc=enc, frontier=improved), done
 
     def frontier_of(self, state):
-        return state["frontier"]
+        return {"rev": state["frontier"]}
 
     def extract(self, state, query):
         enc = state["enc"]  # (MAXK, V)

@@ -44,7 +44,7 @@ class BFSProgram(VertexProgram):
         return dict(dist=state["dist"][t], visited=visited)
 
     def frontier_of(self, state):
-        return state["frontier"]
+        return {"default": state["frontier"]}
 
 
 class BiBFSProgram(VertexProgram):
@@ -84,7 +84,7 @@ class BiBFSProgram(VertexProgram):
         return dict(dist=jnp.minimum(state["best"], INF), visited=visited)
 
     def frontier_of(self, state):
-        return dict(ff=state["ff"], fb=state["fb"])
+        return {"default": state["ff"], "rev": state["fb"]}
 
 
 def make_bibfs_engine(graph: Graph, capacity: int = 8, **kw):

@@ -285,7 +285,7 @@ class ReachQuery(VertexProgram):
         return dict(ds=ds, dt=dt, ff=ff, fb=fb, reach=reach), done
 
     def frontier_of(self, state):
-        return dict(ff=state["ff"], fb=state["fb"])
+        return {"default": state["ff"], "rev": state["fb"]}
 
     def extract(self, state, query):
         visited = ((state["ds"] < INF) | (state["dt"] < INF)).sum()

@@ -43,6 +43,9 @@ class TerrainSSSP(VertexProgram):
         done = early | ~improved.any()
         return dict(d=d, frontier=improved), done
 
+    def frontier_of(self, state):
+        return {"default": state["frontier"]}
+
     def extract(self, state, query):
         t = query[1]
         visited = (state["d"] < FINF).sum()

@@ -57,6 +57,7 @@ Data taxonomy (paper §3.2) maps as:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import threading
 import time
@@ -66,11 +67,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.graph import BlockSparse, EdgeDelta, Graph
 from repro.core.runtime import (
     DONE, QueryTimeoutError, ResumeAdmission, RoundOutcome, SlotProgram,
     SlotRuntime, SlotStats, default_cache_key)
-from repro.core.semiring import Semiring
 from repro.kernels import ops
 
 
@@ -83,6 +84,21 @@ def tree_where(pred, a, b):
         return jnp.where(p, x, y)
 
     return jax.tree.map(sel, a, b)
+
+
+def view_propagate(backends: dict, fns: Optional[dict] = None) -> Callable:
+    """The round's ``propagate(sr, x, frontier, which)``: view ``which``'s
+    plan (``backends[which].propagate``, or ``fns[which]`` where the SPMD
+    round runs a per-device local closure) under the named scope
+    ``propagate.<view>.<plan>``, so a device trace names each view's and
+    plan's ops.  Metadata only: the compiled ops are the same."""
+    fns = fns or {k: be.propagate for k, be in backends.items()}
+
+    def propagate(sr, x, frontier=None, which="default"):
+        with jax.named_scope(f"propagate.{which}.{backends[which].name}"):
+            return fns[which](sr, x, frontier)
+
+    return propagate
 
 
 @dataclasses.dataclass
@@ -107,13 +123,16 @@ class VertexProgram:
                                        for one query; vectorized over V.
     ``extract(state, query)``       -> small result pytree (reported to the
                                        console / dumped, paper's last round).
-    ``frontier_of(state)``          -> optional pytree of (V,) bool masks:
-                                       the vertices this query will activate
-                                       next superstep.  Exposing it lets the
-                                       engine report per-round frontier
-                                       occupancy (``track_frontier=True``)
-                                       and is what sparsity gating reasons
-                                       about (DESIGN.md §3).
+    ``frontier_of(state)``          -> optional ``{view: mask}``: per
+                                       propagation view ('default', 'rev',
+                                       ...), the (..., V) bool mask of the
+                                       vertices whose out-arcs in that view
+                                       the next superstep propagates.  Under
+                                       the profiler the engine counts those
+                                       arcs each round (``core/tracing.py``
+                                       ``FRONTIER_ARCS``); it is also what
+                                       sparsity gating reasons about
+                                       (DESIGN.md §3).
     """
 
     def init(self, graph: Graph, query, index=None):
@@ -136,9 +155,6 @@ class EngineStats(SlotStats):
     ``super_rounds`` and ``barriers`` both read the runtime's round
     counter — one sync per round by construction (DESIGN.md §3)."""
 
-    # per-round active frontier vertex count, only when track_frontier=True
-    # (costs one extra readback per round — diagnostics, not the hot path)
-    frontier_active: list = dataclasses.field(default_factory=list)
     # Times an engine-owned jitted entry point traced+compiled (DESIGN.md
     # §12 addendum): every jit the engine builds routes through
     # ``QuegelEngine._jit``, whose wrapped body runs exactly once per
@@ -182,6 +198,8 @@ class _Edition:
     round_admit: Any = None
     round_resume: Any = None
     round_args: tuple = ()    # SPMD edge parts and/or the arg-carried carrier
+    degrees: dict = None      # frontier view name -> (V,) out-degrees
+    arcs_read: Optional[int] = None  # arcs one dispatch's superstep reads
     admit: Any = None         # legacy: jit per-slot admission
     super_round: Any = None   # legacy: jit (slots, vmask)
 
@@ -233,9 +251,6 @@ class QuegelEngine(SlotProgram):
                 reduces over padded chunks of this many ACTIVE edges
                 instead of all E — for workloads whose frontiers are known
                 to stay small (paper's light-workload regime).
-    track_frontier : record per-round active frontier counts in
-                ``EngineStats.frontier_active`` (extra readback; off the
-                hot path) — requires the program to define ``frontier_of``.
     scheduler : admission policy (DESIGN.md §9) — 'fifo' (default, the
                 paper's behavior), 'priority', 'sjf', 'deadline', or a
                 ``runtime.Scheduler`` instance.  Changes only WHICH
@@ -313,7 +328,6 @@ class QuegelEngine(SlotProgram):
         steps_per_round: int = 1,
         gate: bool = True,
         gather_edges: Optional[int] = None,
-        track_frontier: bool = False,
         mesh: Any = None,
         mesh_axis: Optional[str] = None,
         partition: str = "dst",
@@ -349,7 +363,6 @@ class QuegelEngine(SlotProgram):
             raise ValueError("legacy mode predates multi-superstep rounds")
         self.gate = bool(gate)
         self.gather_edges = gather_edges
-        self.track_frontier = bool(track_frontier)
         self.mesh = mesh
         self.partition = partition
         if mesh is not None:
@@ -519,9 +532,6 @@ class QuegelEngine(SlotProgram):
         return self.runtime.status
 
     # ------------------------------------------------------------ plumbing
-    def _propagate(self, sr: Semiring, x, frontier=None, which: str = "default"):
-        return self._backends[which].propagate(sr, x, frontier)
-
     def _jit(self, fn, version: Optional[int] = None, **jit_kw):
         """``jax.jit`` with compile accounting: the wrapped body runs
         exactly once per trace/compile (never per dispatch), bumping
@@ -531,6 +541,7 @@ class QuegelEngine(SlotProgram):
         closures charge their own version.  This is the counter the
         mutation bench and CI's zero-recompile assertion read."""
 
+        @functools.wraps(fn)  # the compiled module is named jit_<fn>
         def counted(*args):
             self.stats.jit_compiles += 1
             v = self._dispatch_version if version is None else version
@@ -599,24 +610,35 @@ class QuegelEngine(SlotProgram):
             # of finished queries host-side (results are small Q-data).
             self._extract_all = self._jit(extract_all, version=int(g.version))
 
-        # per-round frontier occupancy (opt-in diagnostics): live slots'
-        # active-vertex count, summed over the program's frontier leaves.
-        self._frontier_count = None
-        if self.track_frontier and prog.frontier_of(proto_state) is not None:
+        # Frontier arcs, counted only under the profiler (core/tracing.py
+        # FRONTIER_ARCS): per live slot and view, the out-degrees in that
+        # view of the frontier its next superstep propagates.  Each
+        # edition passes its views' degrees as an argument, so one compile
+        # serves every graph version.
+        self._frontier_views = None
+        self._frontier_warm = False
+        views = prog.frontier_of(proto_state)
+        if views is not None:
+            unknown = set(views) - set(self._backends)
+            if unknown:
+                raise ValueError(
+                    f"frontier_of names views {sorted(unknown)} the engine "
+                    f"does not have: {sorted(self._backends)}"
+                )
+            names = tuple(sorted(views))
 
-            def frontier_count(slots):
-                def one(state, live):
-                    tot = sum(
-                        jnp.sum(leaf)
-                        for leaf in jax.tree.leaves(prog.frontier_of(state))
-                    )
-                    return jnp.where(live, tot, 0)
+            def frontier_arcs(state, live, degrees):
+                def one(st):
+                    fr = prog.frontier_of(st)
+                    return jnp.stack([
+                        jnp.sum(jnp.where(fr[v], degrees[v], 0))
+                        for v in names
+                    ])
 
-                return jax.vmap(one)(slots["state"], slots["live"]).sum()
+                return jnp.where(live[:, None], jax.vmap(one)(state), 0)
 
-            self._frontier_count = self._jit(
-                frontier_count, version=int(g.version)
-            )
+            self._frontier_views = names
+            self._frontier_arcs = self._jit(frontier_arcs)
 
         # Graph versioning (DESIGN.md §12): _slot_version pins each slot to
         # the version it was admitted under; _resume_refs pins editions
@@ -795,8 +817,7 @@ class QuegelEngine(SlotProgram):
         ed = _Edition(version=int(graph.version), graph=graph, index=index,
                       aux=dict(aux), backends=dict(backends))
 
-        def propagate(sr, x, frontier=None, which="default"):
-            return backends[which].propagate(sr, x, frontier)
+        propagate = view_propagate(backends)
 
         m = self._round_machinery(g, index)
 
@@ -823,15 +844,30 @@ class QuegelEngine(SlotProgram):
             warm = getattr(backends[which], "table_for", None)
             if warm is not None:
                 warm(sr)
+        # what the tracing counters compare: the frontier's arcs in each
+        # view, and the arcs the propagate calls read (every slot of the
+        # vmapped call, every lane of x), where the plan can say
+        if self._frontier_views is not None:
+            views = {"default": graph, **ed.aux}
+            ed.degrees = {v: views[v].out_deg for v in self._frontier_views}
+            calls = [sum(w == v for w, _, _, _ in self._prop_trace)
+                     for v in self._frontier_views]
+            self._frontier_weights = np.asarray(calls, np.int64)
+        reads = [(backends[w].arcs_read(), shape)
+                 for w, _, shape, _ in self._prop_trace]
+        if reads and all(a is not None for a, _ in reads):
+            ed.arcs_read = C * sum(a * math.prod(shape[:-1])
+                                   for a, shape in reads)
 
         if self.legacy:
             ed.admit = self._jit(m["admit"], version=ed.version)
             legacy_round = m["make_super_round"](propagate)
             zero_done = m["zero_done"]
-            ed.super_round = self._jit(
-                lambda s, vmask: legacy_round(zero_done(s, vmask), vmask),
-                version=ed.version,
-            )
+
+            def super_round(s, vmask):
+                return legacy_round(zero_done(s, vmask), vmask)
+
+            ed.super_round = self._jit(super_round, version=ed.version)
         elif self.mesh is not None:
             if self._arg_carried:
                 self._bind_spmd_arg_carried(ed)
@@ -851,20 +887,21 @@ class QuegelEngine(SlotProgram):
             dn = (0,) if self.donate else ()
             ed.round = self._jit(round_k, version=ed.version,
                                  donate_argnums=dn)
-            ed.round_admit = self._jit(
-                lambda slots, admit_mask, queries, vmask: round_k(
-                    admit_batch(slots, admit_mask, queries), vmask
-                ),
-                version=ed.version, donate_argnums=dn,
-            )
+
+            def round_admit(slots, admit_mask, queries, vmask):
+                return round_k(admit_batch(slots, admit_mask, queries), vmask)
+
             # separate entry so rounds with no resuming query keep the
             # no-resume hot path (and its compiled trace) untouched
-            ed.round_resume = self._jit(
-                lambda slots, am, q, rm, rst, rsp, vmask: round_k(
+            def round_resume(slots, am, q, rm, rst, rsp, vmask):
+                return round_k(
                     admit_batch_resume(slots, am, q, rm, rst, rsp), vmask
-                ),
-                version=ed.version, donate_argnums=dn,
-            )
+                )
+
+            ed.round_admit = self._jit(round_admit, version=ed.version,
+                                       donate_argnums=dn)
+            ed.round_resume = self._jit(round_resume, version=ed.version,
+                                        donate_argnums=dn)
         return ed
 
     # ------------------------------------------- argument-carried editions
@@ -936,13 +973,9 @@ class QuegelEngine(SlotProgram):
                 k: protos[k].from_args(v)
                 for k, v in carrier["views"].items()
             }
-
-            def prop(sr, x, frontier=None, which="default"):
-                return bes[which].propagate(sr, x, frontier)
-
             return self._round_machinery(
                 carrier["graph"], carrier["index"]
-            ), prop
+            ), view_propagate(bes)
 
         def round_ac(slots, vmask, carrier):
             m, p = machinery_of(carrier)
@@ -1032,12 +1065,8 @@ class QuegelEngine(SlotProgram):
             return jax.tree.map(f, slots, shard_tree)
 
         def local_prop(parts):
-            fns = {k: ed.backends[k].make_local(parts[k]) for k in parts}
-
-            def prop(sr, x, frontier=None, which="default"):
-                return fns[which](sr, x, frontier)
-
-            return prop
+            return view_propagate(ed.backends, {
+                k: ed.backends[k].make_local(parts[k]) for k in parts})
 
         def body_round(slots, vmask, parts):
             rk = make_round_k(local_prop(parts))
@@ -1205,12 +1234,8 @@ class QuegelEngine(SlotProgram):
             return jax.tree.map(f, slots, shard_tree)
 
         def local_prop(parts):
-            fns = {k: protos[k].make_local(parts[k]) for k in parts}
-
-            def prop(sr, x, frontier=None, which="default"):
-                return fns[which](sr, x, frontier)
-
-            return prop
+            return view_propagate(protos, {
+                k: protos[k].make_local(parts[k]) for k in parts})
 
         def body_round(slots, vmask, parts, carrier):
             m = self._round_machinery(carrier["graph"], carrier["index"])
@@ -1418,7 +1443,47 @@ class QuegelEngine(SlotProgram):
         Legacy mode preserves the pre-overhaul structure for the A/B
         baseline: a liveness readback before the round (the extra sync the
         overhaul removed) and one admission dispatch per query.
+
+        Under the JAX profiler (``core/tracing.py``) the dispatch and the
+        readback are the spans ``quegel.dispatch`` and ``quegel.sync``, and
+        the frontier-arc counter is dispatched after the round and read
+        back in the same sync.
         """
+        rid = len(self.stats.round_times)
+        if not self._frontier_warm:
+            self._warm_frontier_counter()
+        with tracing.span(tracing.DISPATCH, round=rid):
+            versions = self._dispatch_round(admitted)
+            arcs = None
+            if tracing.enabled() and self._frontier_views is not None:
+                # the current edition's degrees, for slots of every version
+                arcs = self._frontier_arcs(
+                    self._slots["state"], self._slots["live"],
+                    self._editions[self._current_version].degrees)
+        with tracing.span(tracing.SYNC, round=rid):
+            done, steps, arcs = jax.device_get(
+                (self._slots["done"], self._slots["step"], arcs))
+        if arcs is not None:
+            tracing.count(tracing.FRONTIER_ARCS,
+                          int(arcs.astype(np.int64).sum(0)
+                              @ self._frontier_weights), round=rid)
+            reads = [self._editions[v].arcs_read for v in versions]
+            if None not in reads:
+                tracing.count(tracing.ARCS_READ, sum(reads), round=rid)
+        return RoundOutcome(done=done, steps=steps)
+
+    def _warm_frontier_counter(self) -> None:
+        """Compile the frontier-arc counter on the first round, profiler on
+        or off, so that a profiler started mid-serving compiles nothing."""
+        self._frontier_warm = True
+        if self._frontier_views is not None:
+            self._frontier_arcs(
+                self._slots["state"], self._slots["live"],
+                self._editions[self._current_version].degrees)
+
+    def _dispatch_round(self, admitted: dict[int, Any]) -> list[int]:
+        """``slot_round``'s dispatches; returns the graph versions
+        dispatched."""
         C = self.capacity
         cur = self._current_version
         fresh: dict[int, Any] = {}
@@ -1509,21 +1574,21 @@ class QuegelEngine(SlotProgram):
                         )
                 else:
                     self._slots = ed.round(self._slots, vmask, *ed.round_args)
-        return RoundOutcome(
-            done=np.asarray(self._slots["done"]),
-            steps=np.asarray(self._slots["step"]),
-        )
+        return versions
 
     def slot_collect(self, slots: list[int]) -> list[Any]:
         """Results for retiring slots: ONE vmapped dispatch extracts every
         slot, rows sliced host-side (results are small Q-data); legacy
         extracts per slot, as the pre-overhaul engine did."""
-        if self.legacy:
-            return [
-                jax.tree.map(np.asarray, self._extract(self._slots, int(s)))
-                for s in slots
-            ]
-        all_res = jax.tree.map(np.asarray, self._extract_all(self._slots))
+        with tracing.span(tracing.COLLECT,
+                          round=len(self.stats.round_times)):
+            if self.legacy:
+                return [
+                    jax.tree.map(np.asarray,
+                                 self._extract(self._slots, int(s)))
+                    for s in slots
+                ]
+            all_res = jax.tree.map(np.asarray, self._extract_all(self._slots))
         return [
             jax.tree.map(lambda tab: tab[int(s)], all_res) for s in slots
         ]
@@ -1602,12 +1667,6 @@ class QuegelEngine(SlotProgram):
         ed = self._editions.get(int(self._slot_version[int(slot)]))
         g = self.graph if ed is None else ed.graph
         return g.content_hash() + ":" + default_cache_key(query)
-
-    def slot_observe(self) -> None:
-        if self._frontier_count is not None:
-            self.stats.frontier_active.append(
-                int(self._frontier_count(self._slots))
-            )
 
     # ------------------------------------------------------ graph mutation
     def apply_delta(self, adds=None, dels=None, *, w=None,

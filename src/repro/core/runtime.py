@@ -14,7 +14,6 @@ small ``SlotProgram`` protocol:
     slot_round(admitted) -> RoundOutcome              ONE fused dispatch
     slot_collect(slots)  -> [result, ...]             extract retirees
     slot_evict(slots)                                 kill device liveness
-    slot_observe()                                    per-round diagnostics
 
 The runtime never touches the device: admission is served from a host
 liveness mirror, and everything it learns about a round comes from the
@@ -76,6 +75,8 @@ import time
 from typing import Any, Optional
 
 import numpy as np
+
+from repro.core import tracing
 
 # Terminal query statuses (``SlotRuntime.status[qid]``).
 DONE = "DONE"          # voted done; result extracted
@@ -178,6 +179,8 @@ class Ticket:
     # preserved across suspend/resume so queue_wait measures submission ->
     # first admission once, however often the query is preempted.
     admit_t: float = 0.0
+    # the round (index into SlotStats.round_times) of that first admission
+    admit_round: int = -1
     seq: int = 0              # submission order; ties break FIFO
     # supersteps already charged to this query (nonzero only for a resume
     # ticket): sjf ranks by REMAINING work, and the TIMEOUT bound keeps
@@ -655,10 +658,6 @@ class SlotProgram:
             "restore per-slot state (DESIGN.md §9)"
         )
 
-    def slot_observe(self) -> None:
-        """Optional per-round diagnostics hook (e.g. frontier tracking)."""
-        return None
-
     def cache_key(self, query) -> str:
         return default_cache_key(query)
 
@@ -886,7 +885,9 @@ class SlotRuntime:
                     continue
             slot = free.pop()
             if tk.admit_t == 0.0:
-                tk = dataclasses.replace(tk, admit_t=time.perf_counter())
+                tk = dataclasses.replace(
+                    tk, admit_t=time.perf_counter(),
+                    admit_round=len(self.stats.round_times))
             if tk.resume is None:
                 admitted[slot] = tk.query
             else:
@@ -980,15 +981,26 @@ class SlotRuntime:
         """Admit (+ preempt) + one program round + retire.  Returns the
         retired [(qid, result, status)] — empty if the round completed
         nothing — or None when there was nothing to run (no live slots,
-        nothing admissible)."""
+        nothing admissible).
+
+        Under the JAX profiler the round is the step span ``quegel.round``
+        numbered by its index into ``stats.round_times``, with children
+        ``quegel.admit`` and ``quegel.retire`` here and the program's own
+        (``core/tracing.py``)."""
         t0 = time.perf_counter()
-        self._ticks += 1
-        self._release_retries()
-        admitted: dict[int, Any] = {}
-        free = [i for i in range(self.capacity) if not self.live[i]]
-        self._admit_from_queue(free, admitted)
-        if self.preemptive:
-            self._preempt(admitted)
+        rid = len(self.stats.round_times)
+        with tracing.span(tracing.ROUND, round=rid):
+            return self._run_round(t0, rid)
+
+    def _run_round(self, t0: float, rid: int):
+        with tracing.span(tracing.ADMIT, round=rid):
+            self._ticks += 1
+            self._release_retries()
+            admitted: dict[int, Any] = {}
+            free = [i for i in range(self.capacity) if not self.live[i]]
+            self._admit_from_queue(free, admitted)
+            if self.preemptive:
+                self._preempt(admitted)
         if not self.live.any():
             return None
         self.stats.max_inflight = max(self.stats.max_inflight, self.inflight())
@@ -998,7 +1010,35 @@ class SlotRuntime:
         # coherence before re-raising so a supervisor can keep draining.
         try:
             out = self.program.slot_round(admitted)
-            t_done = time.perf_counter()
+        except Exception:
+            self._abandon_live_slots()
+            raise
+        t_done = time.perf_counter()
+        with tracing.span(tracing.RETIRE, round=rid):
+            completed, retired = self._retire(out, t_done)
+        self.stats.rounds += 1
+        self.stats.slot_occupancy.append(occupancy)
+        t_end = time.perf_counter()
+        dt = t_end - t0
+        self.stats.round_times.append(dt)
+        if tracing.enabled():
+            for tk, status in retired:
+                tracing.query(tk.qid, status, tk.submit_t,
+                              tk.admit_t or tk.submit_t, t_end,
+                              tk.admit_round, rid)
+        if self.straggler is not None and self.straggler.record(
+                self.stats.rounds, dt):
+            self.stats.straggler_rounds += 1
+        if (self.snapshot_every > 0 and self.journal is not None
+                and self.stats.rounds % self.snapshot_every == 0):
+            self.snapshot()
+        return completed
+
+    def _retire(self, out: RoundOutcome, t_done: float):
+        """Evict budget-exhausted slots, collect every retiring slot's
+        result and retire it.  Returns the round's ``(qid, result,
+        status)`` list and the ``(ticket, status)`` of each retirement."""
+        try:
             done = np.asarray(out.done)
             steps = np.asarray(out.steps)
             # refresh the per-slot superstep mirror for live slots only (a
@@ -1024,6 +1064,7 @@ class SlotRuntime:
             self._abandon_live_slots()
             raise
         completed: list[tuple[int, Any, str]] = []
+        retired: list[tuple[Ticket, str]] = []
         for slot, res in zip(retiring, collected):
             tk = self._slot_ticket.pop(slot)
             self.live[slot] = False
@@ -1052,6 +1093,7 @@ class SlotRuntime:
                     self.journal.retire(tk.qid, POISONED, int(steps[slot]),
                                         res)
                 completed.append((tk.qid, res, POISONED))
+                retired.append((tk, POISONED))
                 continue
             status = DONE if slot in finished else TIMEOUT
             self.results[tk.qid] = res
@@ -1081,18 +1123,8 @@ class SlotRuntime:
             if self.journal is not None:
                 self.journal.retire(tk.qid, status, int(steps[slot]), res)
             completed.append((tk.qid, res, status))
-        self.stats.rounds += 1
-        self.stats.slot_occupancy.append(occupancy)
-        self.program.slot_observe()
-        dt = time.perf_counter() - t0
-        self.stats.round_times.append(dt)
-        if self.straggler is not None and self.straggler.record(
-                self.stats.rounds, dt):
-            self.stats.straggler_rounds += 1
-        if (self.snapshot_every > 0 and self.journal is not None
-                and self.stats.rounds % self.snapshot_every == 0):
-            self.snapshot()
-        return completed
+            retired.append((tk, status))
+        return completed, retired
 
     # ------------------------------------------------------------ open loop
     def pump(self) -> list[tuple[int, Any, str]]:

@@ -81,6 +81,12 @@ class PropagateBackend:
     def propagate(self, sr: Semiring, x: jnp.ndarray, frontier=None) -> jnp.ndarray:
         raise NotImplementedError
 
+    def arcs_read(self) -> Optional[int]:
+        """Arcs one ``propagate`` call reads per lane of ``x``, for the
+        tracing counter ``quegel.arcs_read``; None where the plan cannot
+        say (tile plans read whole tiles)."""
+        return None
+
     def export_tables(self):
         """Prepared per-semiring state worth persisting (core/store.py):
         ``{sr.name: BlockSparse}`` for tile backends, else None.  A future
@@ -148,6 +154,14 @@ class CooBackend(PropagateBackend):
                 self.graph, sr, x, frontier, int(self.gather_edges)
             )
         return ref.propagate_coo(self.graph, sr, x, frontier)
+
+    def arcs_read(self):
+        """The padded arc count of the dense path; the chunk size of the
+        gated one (a lower bound: it reads one chunk per
+        ``gather_edges`` active arcs)."""
+        if self.gate and self.gather_edges:
+            return int(self.gather_edges)
+        return int(self.graph.src.shape[0])
 
     def refresh(self, graph, delta=None):
         # no prepared state beyond the graph views, which apply_delta

@@ -2,6 +2,7 @@
 (DESIGN.md §3): gating and superstep fusion are pure optimizations — the
 qid -> result maps and `EngineStats` accounting must be indistinguishable
 from the dense single-step engine, including admission mid-stream."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,6 +10,7 @@ import pytest
 from repro.apps.hub2 import build_hub_index, make_hub2_engine
 from repro.apps.keyword import MAXK, make_keyword_engine, make_vertex_text
 from repro.apps.ppsp import make_bfs_engine, make_bibfs_engine
+from repro.core import tracing
 
 
 def _pairs(graph, n_pairs, seed=0):
@@ -166,19 +168,24 @@ def test_hub2_query_on_tile_backend(small_undirected):
 
 
 # -------------------------------------------------------- frontier stats
-def test_track_frontier_records_occupancy(small_directed):
+def test_track_frontier_records_occupancy(small_directed, tmp_path):
+    """Under the profiler every round records the frontier arcs its live
+    slots lead out of (core/tracing.py); with the profiler off nothing is
+    recorded and no counter is dispatched."""
     g = small_directed
-    eng = make_bfs_engine(g, capacity=4, track_frontier=True)
-    for p in _pairs(g, 6, seed=71):
-        eng.submit(jnp.asarray(p, jnp.int32))
-    eng.run_until_drained()
-    fa = eng.stats.frontier_active
-    assert len(fa) == eng.stats.super_rounds
-    assert all(c >= 0 for c in fa)
-    assert max(fa) > 0
-    # off by default: no extra readback on the hot path
-    eng2 = make_bfs_engine(g, capacity=4)
-    for p in _pairs(g, 4, seed=72):
-        eng2.submit(jnp.asarray(p, jnp.int32))
-    eng2.run_until_drained()
-    assert eng2.stats.frontier_active == []
+    eng = make_bfs_engine(g, capacity=4)
+    _drain(eng, _pairs(g, 4, seed=72))  # warm: the profiler must not compile
+    tracing.clear()
+    r0 = eng.stats.super_rounds
+    with jax.profiler.trace(str(tmp_path)):
+        _drain(eng, _pairs(g, 6, seed=71))
+    rounds = range(r0, eng.stats.super_rounds)
+    fa = [r for r in tracing.records() if r.kind == "count"
+          and r.name == tracing.FRONTIER_ARCS]
+    assert [r.round for r in fa] == list(rounds)
+    assert all(r.value >= 0 for r in fa)
+    assert max(r.value for r in fa) > 0
+    # off: no record, no extra dispatch or readback on the hot path
+    tracing.clear()
+    _drain(eng, _pairs(g, 4, seed=73))
+    assert tracing.records() == []
