@@ -199,7 +199,9 @@ class _Edition:
     round_resume: Any = None
     round_args: tuple = ()    # SPMD edge parts and/or the arg-carried carrier
     degrees: dict = None      # frontier view name -> (V,) out-degrees
-    arcs_read: Optional[int] = None  # arcs one dispatch's superstep reads
+    plans: dict = None        # view name -> as_args() of plans that can
+                              # say what a call reads (read_plan)
+    count_admit: Any = None   # frontier counters of admission rounds
     admit: Any = None         # legacy: jit per-slot admission
     super_round: Any = None   # legacy: jit (slots, vmask)
 
@@ -244,13 +246,14 @@ class QuegelEngine(SlotProgram):
                 admission still happens at round boundaries only.
     gate      : sparsity gating (DESIGN.md §3): tile backends skip
                 frontier-dead adjacency tiles instead of pre-masking x
-                densely.  ``gate=False`` is the dense A/B baseline.
-                (No effect on the sharded backend, which combines densely
-                over each device's edge shard.)
-    gather_edges : when set (coo backend), frontier-carrying propagation
-                reduces over padded chunks of this many ACTIVE edges
-                instead of all E — for workloads whose frontiers are known
-                to stay small (paper's light-workload regime).
+                densely, and the coo backend reads only the frontier's
+                out-arcs where they are few enough.  ``gate=False`` is
+                the dense A/B baseline.  (No effect on the sharded
+                backend, which combines densely over each device's edge
+                shard.)
+    gather_edges : coo backend: the arcs its frontier pass reads a trip,
+                in place of the share of the view's arcs it otherwise
+                takes.
     scheduler : admission policy (DESIGN.md §9) — 'fifo' (default, the
                 paper's behavior), 'priority', 'sjf', 'deadline', or a
                 ``runtime.Scheduler`` instance.  Changes only WHICH
@@ -610,11 +613,16 @@ class QuegelEngine(SlotProgram):
             # of finished queries host-side (results are small Q-data).
             self._extract_all = self._jit(extract_all, version=int(g.version))
 
-        # Frontier arcs, counted only under the profiler (core/tracing.py
-        # FRONTIER_ARCS): per live slot and view, the out-degrees in that
-        # view of the frontier its next superstep propagates.  Each
-        # edition passes its views' degrees as an argument, so one compile
-        # serves every graph version.
+        # The round's frontier counters, only under the profiler
+        # (core/tracing.py): dispatched before each version's round on the
+        # slot table as the round will see it (this round's admissions
+        # applied), so they describe the frontiers the round propagates.
+        # Per slot and view, the out-degrees of that frontier; per
+        # propagate call, its plan's decision and arcs read (``read_plan``,
+        # the frontier masked to the advancing slots as in
+        # ``make_super_round``).  Each edition passes its degrees, plan
+        # arrays and, for admissions, graph and index as arguments, so one
+        # compile serves every graph version.
         self._frontier_views = None
         self._frontier_warm = False
         views = prog.frontier_of(proto_state)
@@ -627,17 +635,46 @@ class QuegelEngine(SlotProgram):
                 )
             names = tuple(sorted(views))
 
-            def frontier_arcs(state, live, degrees):
-                def one(st):
-                    fr = prog.frontier_of(st)
-                    return jnp.stack([
-                        jnp.sum(jnp.where(fr[v], degrees[v], 0))
-                        for v in names
-                    ])
+            def counts(slots, vmask, degrees, plans, admission, m):
+                if admission:
+                    fill = m["admit_batch_resume" if len(admission) > 2
+                             else "admit_batch"]
+                    slots = fill(slots, *admission)
+                adv = slots["live"] & vmask
+                fr = jax.vmap(prog.frontier_of)(slots["state"])
+                fr = {v: f & adv.reshape((C,) + (1,) * (f.ndim - 1))
+                      for v, f in fr.items()}
+                arcs = jnp.stack([
+                    jnp.where(fr[v], degrees[v], 0).reshape(C, -1).sum(1)
+                    for v in names], -1)
+                took, reads = [], []
+                for which, sr, shape, has_frontier in self._prop_calls:
+                    plan = None
+                    if which in plans and (not has_frontier or which in fr):
+                        f = None
+                        if has_frontier:  # as the slot passes it: lanes lead
+                            f = fr[which]
+                            f = f.reshape((C,) + (1,) * (len(shape) + 1
+                                                         - f.ndim)
+                                          + f.shape[1:])
+                        plan = self._backends[which].from_args(
+                            plans[which]).read_plan(sr, (C,) + shape, f)
+                    took.append(False if plan is None else plan[0])
+                    reads.append(-1 if plan is None else plan[1])
+                return (arcs, jnp.array(took, bool).reshape(-1),
+                        jnp.array(reads, jnp.int32).reshape(-1))
 
-                return jnp.where(live[:, None], jax.vmap(one)(state), 0)
+            def frontier_arcs(slots, vmask, degrees, plans, admission=(),
+                              carrier=None):
+                m = (self._round_machinery(carrier["graph"], carrier["index"])
+                     if admission else None)
+                return counts(slots, vmask, degrees, plans, admission, m)
 
+            self._frontier_counts = counts
             self._frontier_views = names
+            # admissions of constant-closure editions go through
+            # ``_Edition.count_admit``, which closes over the edition's
+            # graph and index as its rounds do
             self._frontier_arcs = self._jit(frontier_arcs)
 
         # Graph versioning (DESIGN.md §12): _slot_version pins each slot to
@@ -736,11 +773,19 @@ class QuegelEngine(SlotProgram):
             round entry via ``zero_done``."""
 
             def one(state, query, step, adv):
+                def prop_adv(sr, x, frontier=None, which="default"):
+                    # a slot that does not advance reads no arc: its output
+                    # is discarded, and a stale frontier would sway the
+                    # round's one frontier-or-dense decision
+                    if frontier is not None:
+                        frontier = frontier & adv
+                    return prop(sr, x, frontier, which)
+
                 ctx = StepCtx(
                     graph=g,
                     query=query,
                     step=step + 1,  # Pregel supersteps are 1-based
-                    propagate=prop,
+                    propagate=prop_adv,
                     index=index,
                 )
                 new_state, done = prog.superstep(state, ctx)
@@ -833,31 +878,33 @@ class QuegelEngine(SlotProgram):
 
         def recording(sr, x, frontier=None, which="default"):
             self._prop_trace.append(
-                (which, sr, tuple(x.shape), np.dtype(x.dtype))
+                (which, sr, tuple(x.shape), np.dtype(x.dtype),
+                 frontier is not None)
             )
             return x
 
         jax.eval_shape(
             m["make_round_k"](recording), self._slots, jnp.zeros((C,), bool)
         )
-        for which, sr, _, _ in self._prop_trace:
+        for which, sr, *_ in self._prop_trace:
             warm = getattr(backends[which], "table_for", None)
             if warm is not None:
                 warm(sr)
-        # what the tracing counters compare: the frontier's arcs in each
-        # view, and the arcs the propagate calls read (every slot of the
-        # vmapped call, every lane of x), where the plan can say
+        # what the tracing counters read: each view's out-degrees, each
+        # view's plan arrays where its plan can say what a call reads, and
+        # the program's propagate calls (the same for every edition)
+        self._prop_calls = [(w, sr, shape, has_f)
+                            for w, sr, shape, _, has_f in self._prop_trace]
         if self._frontier_views is not None:
             views = {"default": graph, **ed.aux}
             ed.degrees = {v: views[v].out_deg for v in self._frontier_views}
-            calls = [sum(w == v for w, _, _, _ in self._prop_trace)
+            calls = [sum(w == v for w, *_ in self._prop_trace)
                      for v in self._frontier_views]
             self._frontier_weights = np.asarray(calls, np.int64)
-        reads = [(backends[w].arcs_read(), shape)
-                 for w, _, shape, _ in self._prop_trace]
-        if reads and all(a is not None for a, _ in reads):
-            ed.arcs_read = C * sum(a * math.prod(shape[:-1])
-                                   for a, shape in reads)
+            ed.plans = {
+                v: be.as_args() for v, be in backends.items()
+                if type(be).read_plan is not ops.PropagateBackend.read_plan
+            }
 
         if self.legacy:
             ed.admit = self._jit(m["admit"], version=ed.version)
@@ -1136,7 +1183,7 @@ class QuegelEngine(SlotProgram):
         # entry per propagate call per superstep, each a (C, ..., V) slab.
         prop_bytes = sum(
             int(np.prod(shape)) * dt.itemsize
-            for _, _, shape, dt in self._prop_trace
+            for _, _, shape, dt, _ in self._prop_trace
         )
         state_bytes = sum(
             int(np.prod(np.shape(leaf))) * np.dtype(leaf.dtype).itemsize
@@ -1301,7 +1348,7 @@ class QuegelEngine(SlotProgram):
         # same collective model as the constant-closure SPMD build
         prop_bytes = sum(
             int(np.prod(shape)) * dt.itemsize
-            for _, _, shape, dt in self._prop_trace
+            for _, _, shape, dt, _ in self._prop_trace
         )
         state_bytes = sum(
             int(np.prod(np.shape(leaf))) * np.dtype(leaf.dtype).itemsize
@@ -1446,44 +1493,77 @@ class QuegelEngine(SlotProgram):
 
         Under the JAX profiler (``core/tracing.py``) the dispatch and the
         readback are the spans ``quegel.dispatch`` and ``quegel.sync``, and
-        the frontier-arc counter is dispatched after the round and read
-        back in the same sync.
+        the frontier counters are dispatched before each version's round
+        and read back in the same sync.
         """
         rid = len(self.stats.round_times)
         if not self._frontier_warm:
             self._warm_frontier_counter()
+        counts = ([] if tracing.enabled() and self._frontier_views is not None
+                  else None)
         with tracing.span(tracing.DISPATCH, round=rid):
-            versions = self._dispatch_round(admitted)
-            arcs = None
-            if tracing.enabled() and self._frontier_views is not None:
-                # the current edition's degrees, for slots of every version
-                arcs = self._frontier_arcs(
-                    self._slots["state"], self._slots["live"],
-                    self._editions[self._current_version].degrees)
+            self._dispatch_round(admitted, counts)
         with tracing.span(tracing.SYNC, round=rid):
-            done, steps, arcs = jax.device_get(
-                (self._slots["done"], self._slots["step"], arcs))
-        if arcs is not None:
-            tracing.count(tracing.FRONTIER_ARCS,
-                          int(arcs.astype(np.int64).sum(0)
-                              @ self._frontier_weights), round=rid)
-            reads = [self._editions[v].arcs_read for v in versions]
-            if None not in reads:
-                tracing.count(tracing.ARCS_READ, sum(reads), round=rid)
+            done, steps, counts = jax.device_get(
+                (self._slots["done"], self._slots["step"], counts))
+        if counts:
+            self._record_counts(counts, rid)
         return RoundOutcome(done=done, steps=steps)
 
+    def _count_frontiers(self, ed: _Edition, vmask, admission=()):
+        """Dispatch the frontier counters for ``ed``'s slots in ``vmask``,
+        on the slot table as ``ed``'s round will see it: ``admission`` is
+        the round's admission arguments (masks, queries, resume state)."""
+        args = (self._slots, vmask, ed.degrees, ed.plans)
+        if not admission:
+            return self._frontier_arcs(*args)
+        if self._arg_carried:
+            return self._frontier_arcs(*args, admission, ed.round_args[-1])
+        if ed.count_admit is None:
+            m = self._round_machinery(ed.graph, ed.index)
+            counts = self._frontier_counts
+
+            def frontier_arcs(slots, vmask, degrees, plans, admission):
+                return counts(slots, vmask, degrees, plans, admission, m)
+
+            ed.count_admit = self._jit(frontier_arcs, version=ed.version)
+        return ed.count_admit(*args, admission)
+
+    def _record_counts(self, counts: list, rid: int) -> None:
+        """The round's counters from each dispatched version's readback:
+        frontier arcs (each view's once per call on it), calls, calls that
+        took the frontier path, and arcs read where every call's plan can
+        say."""
+        arcs = sum(a.astype(np.int64).sum(0) for a, _, _ in counts)
+        took = np.concatenate([t for _, t, _ in counts])
+        reads = np.concatenate([r for _, _, r in counts]).astype(np.int64)
+        tracing.count(tracing.FRONTIER_ARCS, int(arcs @ self._frontier_weights),
+                      round=rid)
+        tracing.count(tracing.PROPAGATE_CALLS, len(took), round=rid)
+        tracing.count(tracing.FRONTIER_PATH, int(took.sum()), round=rid)
+        if len(reads) and (reads >= 0).all():
+            tracing.count(tracing.ARCS_READ, int(reads.sum()), round=rid)
+
     def _warm_frontier_counter(self) -> None:
-        """Compile the frontier-arc counter on the first round, profiler on
-        or off, so that a profiler started mid-serving compiles nothing."""
+        """Compile the frontier counters on the first round, profiler on
+        or off, with and without admissions, so that a profiler started
+        mid-serving compiles nothing."""
         self._frontier_warm = True
         if self._frontier_views is not None:
-            self._frontier_arcs(
-                self._slots["state"], self._slots["live"],
-                self._editions[self._current_version].degrees)
+            C = self.capacity
+            ed = self._editions[self._current_version]
+            zmask = np.zeros((C,), bool)
+            queries = jax.tree.map(lambda x: np.stack([x] * C),
+                                   self._proto_q_np)
+            self._count_frontiers(ed, zmask)
+            if not self.legacy:
+                self._count_frontiers(ed, zmask, (zmask, queries))
 
-    def _dispatch_round(self, admitted: dict[int, Any]) -> list[int]:
-        """``slot_round``'s dispatches; returns the graph versions
-        dispatched."""
+    def _dispatch_round(self, admitted: dict[int, Any],
+                        counts: Optional[list] = None) -> None:
+        """``slot_round``'s dispatches; with ``counts`` a list, each
+        version's frontier counters are dispatched before its round and
+        appended there."""
         C = self.capacity
         cur = self._current_version
         fresh: dict[int, Any] = {}
@@ -1532,6 +1612,9 @@ class QuegelEngine(SlotProgram):
             for v in versions:
                 self._dispatch_version = v
                 vmask = (self._slot_version == v) & live
+                if counts is not None:
+                    counts.append(self._count_frontiers(self._editions[v],
+                                                        vmask))
                 self._slots = self._editions[v].super_round(
                     self._slots, vmask
                 )
@@ -1563,18 +1646,26 @@ class QuegelEngine(SlotProgram):
                         rstate = jax.tree.map(
                             lambda *xs: np.stack(xs), *by_state
                         )
+                        if counts is not None:
+                            counts.append(self._count_frontiers(
+                                ed, vmask, (admit_mask, queries, resume_mask,
+                                            rstate, rsteps)))
                         self._slots = ed.round_resume(
                             self._slots, admit_mask, queries, resume_mask,
                             rstate, rsteps, vmask, *ed.round_args
                         )
                     else:
+                        if counts is not None:
+                            counts.append(self._count_frontiers(
+                                ed, vmask, (admit_mask, queries)))
                         self._slots = ed.round_admit(
                             self._slots, admit_mask, queries, vmask,
                             *ed.round_args
                         )
                 else:
+                    if counts is not None:
+                        counts.append(self._count_frontiers(ed, vmask))
                     self._slots = ed.round(self._slots, vmask, *ed.round_args)
-        return versions
 
     def slot_collect(self, slots: list[int]) -> list[Any]:
         """Results for retiring slots: ONE vmapped dispatch extracts every

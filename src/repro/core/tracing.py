@@ -44,9 +44,11 @@ DISPATCH = "quegel.dispatch"  # slot_round entry until the jitted call returns
 SYNC = "quegel.sync"          # the round's done/step readback
 RETIRE = "quegel.retire"      # eviction, collect, retirement bookkeeping
 COLLECT = "quegel.collect"    # extract_all dispatch and readback
-# counters, per round
-FRONTIER_ARCS = "quegel.frontier_arcs"  # arcs leaving live slots' frontiers
-ARCS_READ = "quegel.arcs_read"          # arcs the round's propagates read
+# counters, per round, of the frontiers the round propagates
+FRONTIER_ARCS = "quegel.frontier_arcs"    # arcs leaving advancing slots' frontiers
+ARCS_READ = "quegel.arcs_read"            # arcs the round's propagates read
+PROPAGATE_CALLS = "quegel.propagate_calls"  # the round's propagate calls
+FRONTIER_PATH = "quegel.frontier_path"    # ... that read only frontier arcs
 
 MAX_RECORDS = 1 << 18
 

@@ -10,11 +10,11 @@ This is the logical/physical split Pregelix applies to Pregel plans: the
 engine (``core/engine.py``) holds one backend per named propagation view
 and never branches on *how* messages move.  Concrete plans:
 
-  * ``coo``        — segment-reduction reference (exact; the CPU-fast path
-                     in this container); with ``gather_edges`` set it
-                     reduces over chunks of the ACTIVE edge subset when a
-                     frontier is given,
-  * ``coo_gated``  — the same with the active-edge gather always on,
+  * ``coo``        — segment reduction over the COO view; with a frontier
+                     and a min/max semiring it reads only the frontier's
+                     out-arcs (through the CSR offsets) when they are few
+                     enough, else every arc (``ref.propagate_coo_frontier``),
+  * ``coo_gated``  — the same with ``gather_edges`` arcs a trip,
   * ``blocks_ref`` — jnp oracle over block-sparse dense tiles,
   * ``pallas``     — the Pallas frontier kernel (compiled on a TPU,
                      interpreted on the CPU for validation),
@@ -81,10 +81,12 @@ class PropagateBackend:
     def propagate(self, sr: Semiring, x: jnp.ndarray, frontier=None) -> jnp.ndarray:
         raise NotImplementedError
 
-    def arcs_read(self) -> Optional[int]:
-        """Arcs one ``propagate`` call reads per lane of ``x``, for the
-        tracing counter ``quegel.arcs_read``; None where the plan cannot
-        say (tile plans read whole tiles)."""
+    def read_plan(self, sr: Semiring, shape, frontier=None):
+        """What one ``propagate`` call on x of ``shape`` with this
+        frontier does, for the tracing counters (``core/tracing.py``):
+        ``(took the frontier path, arcs read)`` as traced scalars, decided
+        as ``propagate`` decides; None where the plan cannot say (tile
+        plans read whole tiles)."""
         return None
 
     def export_tables(self):
@@ -133,11 +135,12 @@ class PropagateBackend:
 
 
 class CooBackend(PropagateBackend):
-    """Segment-reduction over the destination-sorted COO view.
+    """Segment reduction over the destination-sorted COO view.
 
-    With ``gather_edges`` set and a frontier given, reduces over padded
-    chunks of the ACTIVE edge subset via the graph's CSR view instead —
-    exact for any frontier size (DESIGN.md §3).
+    With a frontier, a min/max semiring and ``gate`` on, each call reads
+    only the frontier's out-arcs where they are few enough, else every arc
+    (``ref.propagate_coo_frontier``; DESIGN.md §3).  ``gather_edges`` sets
+    the arcs a trip, which otherwise follow from the graph.
     """
 
     name = "coo"
@@ -149,19 +152,15 @@ class CooBackend(PropagateBackend):
         self.gate = bool(gate)
 
     def propagate(self, sr, x, frontier=None):
-        if self.gate and self.gather_edges and frontier is not None:
-            return ref.propagate_coo_gated(
-                self.graph, sr, x, frontier, int(self.gather_edges)
-            )
+        if self.gate and frontier is not None:
+            return ref.propagate_coo_frontier(
+                self.graph, sr, x, frontier, self.gather_edges)
         return ref.propagate_coo(self.graph, sr, x, frontier)
 
-    def arcs_read(self):
-        """The padded arc count of the dense path; the chunk size of the
-        gated one (a lower bound: it reads one chunk per
-        ``gather_edges`` active arcs)."""
-        if self.gate and self.gather_edges:
-            return int(self.gather_edges)
-        return int(self.graph.src.shape[0])
+    def read_plan(self, sr, shape, frontier=None):
+        return ref.frontier_plan(self.graph, sr, shape,
+                                 frontier if self.gate else None,
+                                 self.gather_edges)
 
     def refresh(self, graph, delta=None):
         # no prepared state beyond the graph views, which apply_delta
@@ -422,8 +421,8 @@ def propagate(
     ``blocks`` may be a dict keyed by semiring name (programs mixing
     semirings on one view need one tile table per add-identity); a tile
     backend without a matching table refuses rather than rebuilding.
-    ``gather_edges`` (coo only) reduces over chunks of the active-edge
-    subset instead of all E when a frontier is given.
+    ``gather_edges`` (coo only) sets the arcs a frontier pass reads a
+    trip.
     """
     be = make_backend(
         backend,

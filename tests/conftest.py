@@ -25,6 +25,25 @@ def ba_graph():
     return barabasi_albert(120, 3, seed=3, directed=False)
 
 
+@pytest.fixture
+def frontier_passes(monkeypatch) -> list:
+    """Patch the coo plan's frontier pass to record, as it runs, the arcs
+    it was handed: one entry per call that took the pass (read after
+    ``jax.effects_barrier()``).  Engines built after the patch trace it."""
+    import jax
+
+    from repro.kernels import ref
+
+    totals, real = [], ref._frontier_pass
+
+    def recording(graph, sr, xf, d, total, sizes):
+        jax.debug.callback(lambda t: totals.append(int(t)), total)
+        return real(graph, sr, xf, d, total, sizes)
+
+    monkeypatch.setattr(ref, "_frontier_pass", recording)
+    return totals
+
+
 def nx_of(graph: Graph, directed: bool = True):
     import networkx as nx
 

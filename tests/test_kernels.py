@@ -145,9 +145,10 @@ def test_frontier_mask_parity_tile_backends(sr, backend, gate):
 @pytest.mark.parametrize("sr", SEMIRINGS, ids=lambda s: s.name)
 @pytest.mark.parametrize("chunk", [7, 64, 4096])
 def test_coo_gather_parity(sr, chunk):
-    """The frontier-gated COO gather (chunked active-edge reduction) is
-    exact for any chunk size — including chunks smaller than the active
-    set (multi-iteration while_loop) and larger than E."""
+    """The coo plan with ``gather_edges`` as its arcs a trip is exact for
+    any chunk size: chunks whose trips cannot hold the frontier (the dense
+    fallback), chunks smaller than the frontier's arcs (a multi-trip
+    while_loop) and chunks larger than E."""
     g, x, mask = _masked_case(sr, seed=5)
     want = np.asarray(ref.propagate_coo(g, sr, x, mask))
     got = np.asarray(ops.propagate(g, sr, x, mask, gather_edges=chunk))
@@ -301,3 +302,154 @@ def test_interpret_is_refused_on_a_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(ValueError, match="interpret=True on a TPU"):
         frontier.propagate_blocks(bs, MIN_RIGHT, x, interpret=True)
+
+
+# ------------------------------------------------ frontier-driven coo pass
+SELECTIVE = [MIN_PLUS, MIN_RIGHT, MAX_PLUS, MAX_RIGHT]
+C = 8
+
+
+def _weighted(g, dtype, seed=0):
+    rng = np.random.default_rng(seed)
+    w = rng.integers(1, 9, g.num_edges)
+    return Graph.from_edges(np.asarray(g.src)[: g.num_edges],
+                            np.asarray(g.dst)[: g.num_edges], g.n_real, w=w,
+                            weight_dtype=dtype)
+
+
+def _x(sr, shape, dtype, seed=1):
+    """Values with unreached (add-identity) entries mixed in."""
+    rng = np.random.default_rng(seed)
+    x = rng.integers(0, 50, shape).astype(dtype)
+    return jnp.asarray(np.where(rng.random(shape) < 0.2, sr.add_id, x)
+                       .astype(dtype))
+
+
+def _frontier_of_arcs(g, rows, target, seed=2):
+    """A (rows, V) frontier whose out-arcs over all rows come to at most
+    ``target``, vertices drawn at random into rows in turn."""
+    rng = np.random.default_rng(seed)
+    deg = np.diff(np.asarray(g.csr_row))
+    f = np.zeros((rows, g.n), bool)
+    total = 0
+    for i, v in enumerate(rng.permutation(np.repeat(np.arange(g.n), rows))):
+        r = i % rows
+        if deg[v] and not f[r, v] and total + deg[v] <= target:
+            f[r, v] = True
+            total += deg[v]
+    return jnp.asarray(f), total
+
+
+def _vmapped(g, sr, chunk=None):
+    return jax.jit(jax.vmap(
+        lambda x, f: ref.propagate_coo_frontier(g, sr, x, f, chunk)))
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.float32],
+                         ids=["int32", "float32"])
+@pytest.mark.parametrize("sr", SELECTIVE, ids=lambda s: s.name)
+def test_frontier_pass_is_bit_identical_to_the_dense_pass(sr, dtype):
+    """The frontier pass, called once for one row, against the dense pass:
+    the same messages reach the same destinations, and a min or max has
+    nothing to round."""
+    g = _weighted(random_graph(200, 4.0, seed=4), dtype)
+    x = _x(sr, (g.n,), dtype)
+    f, _ = _frontier_of_arcs(g, 1, 300)
+    took, _ = ref.frontier_plan(g, sr, x.shape, f[0])
+    assert bool(took)
+    got = ref.propagate_coo_frontier(g, sr, x, f[0])
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(ref.propagate_coo(g, sr, x, f[0])))
+
+
+@pytest.mark.parametrize("sr", SELECTIVE, ids=lambda s: s.name)
+@pytest.mark.parametrize("regime", ["empty", "one_trip", "trips", "dense"])
+def test_frontier_pass_under_vmap_in_every_regime(sr, regime):
+    """Under ``vmap`` over C = 8 slots the one decision spans every slot:
+    an empty frontier (no trip), one that fits one trip, one that needs
+    several, and one past the dense threshold; each equal to the dense
+    pass bit for bit."""
+    g = _weighted(random_graph(256, 8.0, seed=6), np.int32)
+    k, t, p = ref.frontier_sizes(g, C)
+    target = {"empty": 0, "one_trip": k, "trips": 3 * k, "dense": 8 * t}[regime]
+    f, total = _frontier_of_arcs(g, C, target)
+    x = _x(sr, (C, g.n), np.int32)
+    took, read = ref.frontier_plan(g, sr, x.shape, f)
+    trips = -(-total // k)
+    if regime == "dense":
+        assert not bool(took) and int(read) == C * g.num_edges
+    else:
+        assert bool(took) and int(read) == trips * k
+        assert {"empty": trips == 0, "one_trip": trips == 1,
+                "trips": trips > 1}[regime]
+    got = _vmapped(g, sr)(x, f)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(ref.propagate_coo(g, sr, x, f)))
+
+
+@pytest.mark.parametrize("sr", SELECTIVE, ids=lambda s: s.name)
+def test_frontier_pass_on_padded_and_mutated_graphs(sr):
+    """Capacity padding (``src = dst = n`` rows past the real arcs) is
+    never read, and an in-capacity ``apply_delta`` keeps ``csr_row``
+    current: both equal the dense pass on the trimmed graph."""
+    base = _weighted(random_graph(120, 4.0, seed=8), np.int32)
+    padded = base.with_capacity(max_e=base.num_edges + 96)
+    s, d = np.asarray(base.src)[:5], np.asarray(base.dst)[:5]
+    mutated = padded.apply_delta(adds=[(3, 17), (40, 41), (7, 90)],
+                                 dels=np.stack([s, d], 1), w=[2, 3, 4])
+    assert mutated.edge_capacity == padded.edge_capacity
+    x = _x(sr, (C, base.n), np.int32)
+    for g in (padded, mutated):
+        f, _ = _frontier_of_arcs(g, C, 200)
+        assert bool(ref.frontier_plan(g, sr, x.shape, f)[0])
+        got = _vmapped(g, sr)(x, f)
+        want = ref.propagate_coo(g.trimmed(), sr, x, f)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("shared", [True, False], ids=["shared", "per_lane"])
+@pytest.mark.parametrize("sr", SELECTIVE, ids=lambda s: s.name)
+def test_frontier_pass_with_lanes(sr, shared):
+    """A multi-lane ``x`` of (L, V) per slot: the lanes are rows of the
+    pass, with one frontier for every lane or one per lane."""
+    g = _weighted(random_graph(150, 4.0, seed=10), np.int32)
+    lanes = 3
+    x = _x(sr, (C, lanes, g.n), np.int32)
+    f, _ = _frontier_of_arcs(g, C * (1 if shared else lanes), 150)
+    f = f.reshape((C, 1 if shared else lanes, g.n))
+    assert bool(ref.frontier_plan(g, sr, x.shape, f)[0])
+    got = _vmapped(g, sr)(x, f)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(ref.propagate_coo(g, sr, x, f)))
+
+
+def test_frontier_pass_lowers_to_one_branch_and_one_loop():
+    """Under ``jit(vmap(...))`` the decision is one real conditional over
+    every slot, and the pass one unbatched ``while_loop``: not a select
+    over both branches, nor a loop run to the largest slot's trips."""
+    g = _weighted(random_graph(64, 3.0, seed=12), np.int32)
+    x = _x(MIN_RIGHT, (C, g.n), np.int32)
+    f = jnp.zeros((C, g.n), bool)
+    text = _vmapped(g, MIN_RIGHT).lower(x, f).as_text()
+    assert text.count("stablehlo.case") == 1
+    assert text.count("stablehlo.while") == 1
+    # the loop carries the flat (C * V + 1) output: no slot axis on it
+    assert f"tensor<{C * g.n + 1}xi32>" in text
+
+
+@pytest.mark.parametrize("sr", [SUM_TIMES, MIN_RIGHT], ids=lambda s: s.name)
+def test_dense_pass_without_frontier_or_selective_semiring(sr):
+    """``sum_times`` and a call with no frontier keep the dense pass."""
+    g = _weighted(random_graph(64, 3.0, seed=14), np.float32)
+    x = jnp.asarray(np.random.default_rng(0).random((C, g.n)), jnp.float32)
+    f = None if sr is MIN_RIGHT else jnp.ones((C, g.n), bool)
+    took, read = ref.frontier_plan(g, sr, x.shape, f)
+    assert not bool(took) and int(read) == C * g.num_edges
+    text = _vmapped(g, sr).lower(x, f).as_text() if f is not None else \
+        jax.jit(lambda x: ref.propagate_coo_frontier(g, sr, x)).lower(x) \
+        .as_text()
+    assert "stablehlo.case" not in text and "stablehlo.while" not in text
+    got = (jax.vmap(lambda x, f: ref.propagate_coo_frontier(g, sr, x, f))(x, f)
+           if f is not None else ref.propagate_coo_frontier(g, sr, x))
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(ref.propagate_coo(g, sr, x, f)))

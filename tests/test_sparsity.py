@@ -118,6 +118,31 @@ def test_engine_coo_gather_matches_dense(small_directed):
     assert gated.stats.supersteps_total == plain.stats.supersteps_total
 
 
+def test_stale_frontier_of_a_finished_slot_reads_nothing(frontier_passes):
+    """A slot that stopped advancing keeps its last frontier; the round
+    hands ``propagate`` its frontier masked by the advance flag, so the
+    round's one decision counts only the advancing slots' arcs.  Here a
+    star query ends at once, its last frontier the star's 99 leaves, while
+    a chain query runs on with 2 arcs a round."""
+    from repro.core.graph import Graph
+
+    chain = np.arange(30)
+    leaves = np.arange(101, 200)
+    src = np.concatenate([chain, chain + 1, np.full(99, 100), leaves])
+    dst = np.concatenate([chain + 1, chain, leaves, np.full(99, 100)])
+    g = Graph.from_edges(src, dst, 200)
+    totals = frontier_passes
+    eng = make_bfs_engine(g, capacity=2)
+    res = _drain(eng, [(0, 30), (100, 101)])
+    jax.effects_barrier()
+    assert totals[0] == 1 + 99  # both slots' first frontiers: {0}, {100}
+    assert totals[1] == 2       # {1} alone: the star's leaves are stale
+    assert set(totals[2:]) == {2}
+    assert len(totals) == 30
+    dense = make_bfs_engine(g, capacity=2, gate=False)
+    assert res == _drain(dense, [(0, 30), (100, 101)])
+
+
 def test_engine_gated_keyword_lanes(small_directed):
     """Multi-lane (MAXK, V) state: keyword search on a tile backend with
     gating == coo reference."""

@@ -1,5 +1,6 @@
-"""Ahead-of-time compiles of the Pallas frontier kernel for a described TPU
-v5e chip: what the TPU compiler refuses shows up here, with no chip.
+"""Ahead-of-time compiles of the Pallas frontier kernel and of the coo
+plan's frontier pass for a described TPU v5e chip: what the TPU compiler
+refuses shows up here, with no chip.
 
 Shapes are a 512x512 terrain's tile table (2,048 destination blocks of
 B = 128, 11 slots with the argument-carried headroom), the size at which a
@@ -15,9 +16,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.graph import BlockSparse
+from repro.core.graph import BlockSparse, Graph
 from repro.core.semiring import MAX_PLUS, MAX_RIGHT, MIN_PLUS, MIN_RIGHT, SUM_TIMES
-from repro.kernels import frontier, ops
+from repro.kernels import frontier, ops, ref
 
 B, NB, MAX_BPR = 128, 2048, 11
 
@@ -105,3 +106,33 @@ def test_integer_sum_times_is_refused():
             lambda bs, x: frontier.propagate_blocks(bs, SUM_TIMES, x, interpret=False),
             _table(sds, jnp.int32), sds((8, NB * B), jnp.int32),
         )
+
+
+# the benchmark's views: Graph500 scale 18 padded to 2 x 16 x 2**18 arcs,
+# and the 256 x 256 terrain mesh at its edge capacity
+COO_CASES = [(MIN_RIGHT, jnp.int32, 262_144, 8_388_608),
+             (MIN_PLUS, jnp.float32, 65_536, 651_584)]
+
+
+@pytest.mark.parametrize("sr,dtype,v,e", COO_CASES,
+                         ids=["kron-min_right-int32", "terrain-min_plus-float32"])
+def test_coo_frontier_pass_compiles_under_vmap_like_the_engine(
+        one_chip, sr, dtype, v, e):
+    """The coo plan as the engine's round calls it, vmapped over C = 8
+    slots on a capacity-padded view: one conditional between the frontier
+    pass and the dense pass, compiled for the chip."""
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    ints = lambda k: sds((k,), jnp.int32)  # noqa: E731
+    g = Graph(n=v, n_real=v, src=ints(e), dst=ints(e), w=sds((e,), dtype),
+              in_deg=ints(v), out_deg=ints(v), csr_row=ints(v + 1),
+              csr_src=ints(e), csr_dst=ints(e), csr_w=sds((e,), dtype),
+              nnz=sds((), jnp.int32))
+
+    def round_prop(g, x, f):
+        return jax.vmap(
+            lambda xv, fv: ref.propagate_coo_frontier(g, sr, xv, fv))(x, f)
+
+    lowered = jax.jit(round_prop).lower(g, sds((8, v), dtype),
+                                        sds((8, v), jnp.bool_))
+    assert "stablehlo.case" in lowered.as_text()
+    lowered.compile()
