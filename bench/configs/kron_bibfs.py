@@ -2,9 +2,10 @@
 
 The plain side of the ``graph500-kron`` configuration: the Graph500
 generator (specification v3, section 3), the query pool (endpoints of
-degree >= 1, as Graph500 draws its search keys), the reference
-(``scipy.sparse.csgraph`` BFS hop counts, nothing of the program), the
-least bytes each query needs, and the control.  ``make_engine`` is the one
+degree >= 1, as Graph500 draws its search keys, at fixed hop distances
+and balls), the reference (``scipy.sparse.csgraph`` BFS hop
+counts, nothing of the program), the least bytes each query needs, and
+the control.  ``make_engine`` is the one
 call into the system under test.
 """
 from __future__ import annotations
@@ -18,6 +19,8 @@ from bench.harness.roofline import least_bytes
 
 # The program reports an unreached target as this distance.
 UNREACHED = 2**30
+# Uniform sources whose pairs with every endpoint ``ball_targets`` reads.
+SOURCES = 256
 
 
 @dataclasses.dataclass
@@ -74,23 +77,75 @@ def make_engine(data: Data, cfg: dict):
 
 
 def pool(data: Data, cfg: dict, rng) -> list:
-    """One (s, t) per entry of ``hop_distances``: s uniform over the
-    endpoints (drawn again while no endpoint lies at that distance from
-    it), t uniform over the endpoints at that hop distance from s.  The
-    distances are those of uniform endpoint pairs at fixed quantiles, so
-    every seed offers the same work (BiBFS runs ceil(d / 2) supersteps)."""
+    """One (s, t) per entry of ``hop_distances`` d, at that entry's
+    ``ball_targets`` (bs, bt): s is the endpoint whose ball lies nearest bs
+    among those with an endpoint at distance d, t the endpoint at distance
+    d from s whose ball lies nearest bt (ties broken by the seed).  The
+    targets are the mean balls of strata of uniform endpoint pairs
+    (``ball_targets``), so every seed offers the same supersteps and,
+    since a query's frontier arcs follow the balls of its ends
+    (``ball``), nearly the same arcs as uniform pairs at those distances."""
     fwd = _matrix(data.n, data.src, data.dst)
-    is_ep = np.zeros(data.n, bool)
-    is_ep[data.endpoints] = True
+    eps, b, tie = data.endpoints, ball(fwd), rng.random(data.n)
     out = []
-    for d in cfg["hop_distances"]:
-        while True:
-            s = int(data.endpoints[rng.integers(len(data.endpoints))])
-            cand = np.flatnonzero((_levels(fwd, s) == d) & is_ep)
+    for d, (bs, bt) in zip(cfg["hop_distances"], cfg["ball_targets"]):
+        for s in eps[np.lexsort((tie[eps], np.abs(b[eps] - bs)))]:
+            cand = eps[_levels(fwd, s)[eps] == d]
             if len(cand):
                 break
-        out.append(np.asarray([s, rng.choice(cand)], np.int32))
+        else:
+            raise ValueError(f"no endpoint pair at distance {d}")
+        t = cand[np.lexsort((tie[cand], np.abs(b[cand] - bt)))[0]]
+        out.append(np.asarray([s, t], np.int32))
     return out
+
+
+def ball(a: csr_matrix) -> np.ndarray:
+    """Each vertex's degree plus its neighbours' degrees (``A @ deg +
+    deg``): a BiBFS query of two supersteps reads ball(s) + ball(t) arcs,
+    one of a single superstep deg(s) + deg(t)."""
+    deg = np.diff(a.indptr)
+    return a @ deg + deg
+
+
+def ball_targets(data: Data, hop_distances: list, rng) -> list:
+    """``ball_targets`` for ``hop_distances``, from the uniform endpoint
+    pairs of ``SOURCES`` seeded sources and every endpoint.  For a distance
+    d that m entries name: the endpoints in order of ball, each weighted by
+    the sources it lies at distance d from (by symmetry s's balls at d are
+    distributed as t's), cut into m strata of equal weight; each stratum's
+    mean ball, from the heaviest stratum down, less what the strata above
+    it overshot, taken to the nearest ball the graph has.  Entry i pairs
+    the i-th lightest target for s with the i-th heaviest for t, so that
+    every t can lie at distance d from a light s or a heavy one, and the
+    pool's balls at each distance sum to those of as many uniform pairs."""
+    fwd = _matrix(data.n, data.src, data.dst)
+    eps = data.endpoints
+    balls = ball(fwd)[eps]
+    order = np.argsort(balls, kind="stable")
+    balls = balls[order]
+    weight = {d: np.zeros(len(eps)) for d in set(hop_distances)}
+    for s in eps[rng.integers(len(eps), size=SOURCES)]:
+        levels = _levels(fwd, s)[eps][order]
+        for d, w in weight.items():
+            w += levels == d
+    picks = {}
+    for d, w in weight.items():
+        m = hop_distances.count(d)
+        cum = np.cumsum(w)
+        cut = np.searchsorted(cum, cum[-1] * np.arange(1, m) / m)
+        means = [np.average(x, weights=y)
+                 for x, y in zip(np.split(balls, cut), np.split(w, cut))]
+        got, over = [], 0.0
+        for mean in means[::-1]:
+            want = mean - over
+            i = np.searchsorted(balls, want).clip(1, len(balls) - 1)
+            near = balls[i] if balls[i] - want < want - balls[i - 1] \
+                else balls[i - 1]
+            got.insert(0, int(near))
+            over = near - want
+        picks[d] = [[got[i], got[m - 1 - i]] for i in range(m)]
+    return [picks[d].pop(0) for d in hop_distances]
 
 
 def served(result) -> int:

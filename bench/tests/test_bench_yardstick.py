@@ -101,7 +101,8 @@ def test_terrain_pool_offers_the_same_lengths_on_every_seed():
 
 def test_kron_pool_offers_the_configured_distances():
     cfg = dict(scale=9, edgefactor=16, initiator=(0.57, 0.19, 0.19, 0.05),
-               hop_distances=[1, 2, 2, 3])
+               hop_distances=[1, 2, 2, 3],
+               ball_targets=[[40, 40], [1, 900], [900, 1], [10**6, 0]])
     data = KRON.build(cfg, np.random.default_rng(4))
     pool = KRON.pool(data, cfg, np.random.default_rng(5))
     ref = KRON.reference(data, cfg, pool)
